@@ -1,8 +1,8 @@
 """AOT executable cache: compiled XLA kernels as distributable data.
 
 Compile time is the fleet's worst cold-start cliff (6.4 s cold on the
-CPU box, 124–133 s compile+first-call on real chips — BENCH_r05 /
-MULTICHIP_r05), paid per worker per shape class, exactly when the
+CPU box, 124–133 s compile+first-call in round-5 chip records, since
+deleted), paid per worker per shape class, exactly when the
 autoscale advisor adds workers under load. This package serializes the
 phase-A / phase-B-ladder / fused-twin executables
 (``jax.jit(...).lower().compile()`` + executable serialization) and

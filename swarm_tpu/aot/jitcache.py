@@ -32,28 +32,35 @@ import jax
 #: bytes into the unpickler (the digest salts the same constant, so in
 #: practice a mismatch is unreachable; the header is belt-and-braces
 #: for artifacts handled outside the store)
-_MAGIC = b"SWAOT1\x00"
+_MAGIC = b"SWAOT2\x00"
 
 
 def serialize_compiled(compiled) -> bytes:
     """One ``jax.stages.Compiled`` → portable bytes (the XLA
-    executable image + the in/out pytree defs it was lowered with)."""
+    executable image, the in/out pytree defs it was lowered with, and
+    the ids of the devices it runs on, in its device-assignment order)."""
     from jax.experimental.serialize_executable import serialize
 
     payload, in_tree, out_tree = serialize(compiled)
-    return _MAGIC + pickle.dumps((payload, in_tree, out_tree))
+    ids = [d.id for d in compiled.runtime_executable().local_devices()]
+    return _MAGIC + pickle.dumps((payload, in_tree, out_tree, ids))
 
 
 def load_compiled(blob: bytes):
-    """Bytes → a callable loaded executable. Raises on any mismatch
-    (header, unpickle, device topology) — callers treat every failure
-    as a cache miss."""
+    """Bytes → a callable loaded executable, bound to the devices it
+    was compiled for (not every local device). Raises on any mismatch
+    (header, unpickle, a device this process lacks) — callers treat
+    every failure as a cache miss."""
     from jax.experimental.serialize_executable import deserialize_and_load
 
     if not blob.startswith(_MAGIC):
         raise ValueError("bad AOT artifact header")
-    payload, in_tree, out_tree = pickle.loads(blob[len(_MAGIC):])
-    return deserialize_and_load(payload, in_tree, out_tree)
+    payload, in_tree, out_tree, ids = pickle.loads(blob[len(_MAGIC):])
+    by_id = {d.id: d for d in jax.devices()}
+    return deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in ids],
+    )
 
 
 def aval_signature(tree) -> str:
@@ -193,19 +200,16 @@ class AotJit:
         """Publisher-path compiles bypass jax's PERSISTENT compilation
         cache: an executable that was itself deserialized from that
         cache re-serializes into a non-self-contained image (XLA:CPU
-        "Symbols not found" at load time — observed on jaxlib 0.4.36),
-        which would poison the store with unloadable artifacts (the
-        publish round-trip verification would then drop EVERY publish
-        instead). A fresh compile serializes cleanly; non-publishing
+        "Function ... not found" when the loaded copy runs — still so
+        on jax 0.9.0), which would poison the store with unloadable
+        artifacts. A fresh compile serializes cleanly; non-publishing
         clients keep the cache (their executables never leave the
         process).
 
-        The config flag alone is not enough: ``compilation_cache.
-        is_cache_used`` memoizes its decision once per process, so
-        the scoped override also flips that memoized state for the
-        duration of the compile (restored after; a concurrent compile
-        on another thread at most loses one cache lookup — perf, not
-        correctness)."""
+        ``is_cache_used`` memoizes its decision per process, so the
+        flag flip is paired with ``reset_cache()`` on both edges (a
+        concurrent compile on another thread at most loses one cache
+        lookup — perf, not correctness)."""
         import contextlib
 
         client = self._client
@@ -214,27 +218,16 @@ class AotJit:
 
         @contextlib.contextmanager
         def no_persistent_cache():
+            from jax.experimental.compilation_cache import compilation_cache
+
+            was = jax.config.jax_enable_compilation_cache
+            jax.config.update("jax_enable_compilation_cache", False)
+            compilation_cache.reset_cache()
             try:
-                from jax._src import config as jax_config
-
-                cfg_ctx = jax_config.enable_compilation_cache(False)
-            except Exception:
-                cfg_ctx = contextlib.nullcontext()
-            with cfg_ctx:
-                try:
-                    from jax._src import compilation_cache as cc
-
-                    with cc._cache_initialized_mutex:
-                        saved = (cc._cache_checked, cc._cache_used)
-                        cc._cache_checked, cc._cache_used = True, False
-                except Exception:
-                    cc = None
-                try:
-                    yield
-                finally:
-                    if cc is not None:
-                        with cc._cache_initialized_mutex:
-                            cc._cache_checked, cc._cache_used = saved
+                yield
+            finally:
+                jax.config.update("jax_enable_compilation_cache", was)
+                compilation_cache.reset_cache()
 
         return no_persistent_cache()
 

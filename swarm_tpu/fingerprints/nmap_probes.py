@@ -239,6 +239,24 @@ def parse_probes(text: str) -> tuple[list[ServiceProbe], int]:
     return probes, skipped
 
 
+def probe_for_port(probes: list[ServiceProbe], port: int) -> ServiceProbe:
+    """Payload selection: the lowest-rarity TCP probe with a payload
+    covering ``port`` (first in DB order on ties); NULL (listen-only)
+    otherwise."""
+    best: Optional[ServiceProbe] = None
+    for probe in probes:
+        if probe.proto != "TCP" or not probe.payload:
+            continue
+        if probe.covers_port(port) and (best is None or probe.rarity < best.rarity):
+            best = probe
+    if best is None:
+        best = next(
+            (p for p in reversed(probes) if p.name == "NULL"),
+            ServiceProbe(proto="TCP", name="NULL"),
+        )
+    return best
+
+
 def load_probes(path: Optional[str | Path] = None) -> tuple[list[ServiceProbe], int]:
     """Load a probes DB: explicit path > system nmap DB > bundled mini DB."""
     p = Path(path) if path else (SYSTEM_DB if SYSTEM_DB.is_file() else BUNDLED_DB)

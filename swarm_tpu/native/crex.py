@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 from pathlib import Path
 from typing import Optional
@@ -64,8 +63,9 @@ def _note_budget_fail(cp) -> None:
 
 
 def ensure_crex() -> Optional[ctypes.CDLL]:
-    """Load libcrex.so (building via make on first use); None when the
-    native lib is unavailable (Python fallback runs). Thread-safe:
+    """Load libcrex.so (building via make on first use; a failed build
+    raises); None when the library does not load or speaks another ABI
+    (Python fallback runs). Thread-safe:
     concurrent first calls serialize on _load_lock."""
     global _lib, _lib_failed
     if _lib is not None:
@@ -92,18 +92,9 @@ def _ensure_crex_locked() -> Optional[ctypes.CDLL]:  # requires-lock: _load_lock
                 f"SWARM_NATIVE_DIR set but {_LIB_PATH} does not exist"
             )
     else:
-        try:
-            import sys as _sys
+        from swarm_tpu.native.scanio import make_native
 
-            subprocess.run(
-                ["make", "-C", str(_SRC_NATIVE_DIR), f"PY={_sys.executable}"],
-                check=True,
-                capture_output=True,
-            )
-        except (OSError, subprocess.CalledProcessError):
-            if not _LIB_PATH.exists():
-                _lib_failed = True
-                return None
+        make_native()  # raises when the committed sources don't build
     try:
         lib = ctypes.CDLL(str(_LIB_PATH))
     except OSError:

@@ -49,6 +49,26 @@ _lib: Optional[ctypes.CDLL] = None  # guarded-by: _load_lock
 _load_lock = threading.Lock()
 
 
+def make_native() -> None:
+    """Build the native libraries from the committed sources
+    (mtime-incremental). A failed build raises: a library left on disk
+    by some other build must never load in place of these sources.
+    Deployments that ship prebuilt libraries name them with
+    ``SWARM_NATIVE_DIR`` and skip this."""
+    import sys as _sys
+
+    r = subprocess.run(
+        ["make", "-C", str(_SRC_NATIVE_DIR), f"PY={_sys.executable}"],
+        capture_output=True,
+        text=True,
+    )
+    if r.returncode != 0:
+        raise RuntimeError(
+            f"native build failed (make -C {_SRC_NATIVE_DIR}, "
+            f"rc {r.returncode}):\n{(r.stdout + r.stderr)[-2000:]}"
+        )
+
+
 def ensure_lib() -> ctypes.CDLL:
     """Load libscanio.so, building it with make on first use.
     Thread-safe: concurrent first calls serialize on _load_lock."""
@@ -63,21 +83,8 @@ def _ensure_lib_locked() -> ctypes.CDLL:  # requires-lock: _load_lock
     global _lib
     if _lib is not None:
         return _lib
-    # invoke make when possible (mtime-incremental, so a stale prebuilt
-    # .so from an older checkout picks up new symbols); a deployment
-    # without a toolchain falls back to the shipped .so
     if not _DIR_OVERRIDDEN:
-        try:
-            import sys as _sys
-
-            subprocess.run(
-                ["make", "-C", str(_SRC_NATIVE_DIR), f"PY={_sys.executable}"],
-                check=True,
-                capture_output=True,
-            )
-        except (OSError, subprocess.CalledProcessError):
-            if not _LIB_PATH.exists():
-                raise
+        make_native()
     elif not _LIB_PATH.exists():
         raise FileNotFoundError(
             f"SWARM_NATIVE_DIR set but {_LIB_PATH} does not exist"

@@ -21,6 +21,7 @@ from swarm_tpu.fingerprints.nmap_probes import (
     ServiceMatch,
     ServiceProbe,
     load_probes,
+    probe_for_port,
     substitute_version,
 )
 
@@ -239,20 +240,10 @@ class ServiceClassifier:
         service scans call this per (host, port) on the probing hot
         path."""
         cached = self._port_probe_cache.get(port)
-        if cached is not None:
-            return cached
-        best: Optional[ServiceProbe] = None
-        for probe in self.probes:
-            if probe.proto != "TCP" or not probe.payload:
-                continue
-            if probe.covers_port(port) and (best is None or probe.rarity < best.rarity):
-                best = probe
-        if best is None:
-            best = self.probe_by_name.get("NULL") or ServiceProbe(
-                proto="TCP", name="NULL"
-            )
-        self._port_probe_cache[port] = best
-        return best
+        if cached is None:
+            cached = probe_for_port(self.probes, port)
+            self._port_probe_cache[port] = cached
+        return cached
 
     def default_payload_probe(self) -> Optional[ServiceProbe]:
         """Second-round probe for silent-but-open ports: the lowest-

@@ -44,8 +44,8 @@ OVERLAPPED reduction, the mesh twin of ``DeviceDB.dispatch``
   collectives. ``collect`` forces the handle if no later dispatch
   already did. One reduction executable serves EVERY ladder rung.
 
-Per-batch uploads go through the dispatch staging pool and are
-DONATED to their last consumer together with the inter-phase rank
+Per-batch uploads land straight in the mesh layout (accounted in the
+dispatch staging pool) and are DONATED to their last consumer together with the inter-phase rank
 planes; the fused single-kernel pjit step is kept as the bit-identical
 reference twin (``SWARM_SHARD_COMPACT=0`` / ``SWARM_SHARD_DONATE=0``,
 or the ``compact=``/``donate=`` args; ``SWARM_SHARD_OVERLAP=0`` keeps
@@ -396,24 +396,22 @@ class ShardedMatcher:
         #: 1: each dispatch flushes its predecessor before parking its
         #: own handle)
         self._pending: Optional[_PendingShard] = None  # guarded-by: _counter_lock
-        # constant after construction — upload once, not per match call
-        if self.multiprocess:
-            self._tab_j = {
-                k: self._global(v, P("model")) for k, v in self._tab_np.items()
-            }
-            self._rep_j = jax.tree_util.tree_map(
-                lambda a: self._global(a, P()), self._rep_np
-            )
-        else:
-            self._tab_j = {k: jnp.asarray(v) for k, v in self._tab_np.items()}
-            self._rep_j = jax.tree_util.tree_map(jnp.asarray, self._rep_np)
+        # constant after construction — upload once, not per match
+        # call, straight into the mesh layout the kernels consume (an
+        # array staged on one device would be resharded every call)
+        self._tab_j = {
+            k: self._global(v, P("model")) for k, v in self._tab_np.items()
+        }
+        self._rep_j = jax.tree_util.tree_map(
+            lambda a: self._global(a, P()), self._rep_np
+        )
         self._fn_cache: dict = {}  # guarded-by: _counter_lock
         for ax, size in self.ranks.items():
             _shard_metrics().MESH_AXIS.labels(axis=ax).set(size)
 
     def _global(self, arr, spec):
-        """Host copy -> global array laid out per ``spec`` over the
-        (possibly multi-process) mesh."""
+        """Host copy -> array laid out per ``spec`` over the mesh
+        (global across processes on a multi-process mesh)."""
         arr = np.asarray(arr)
         sharding = NamedSharding(self.mesh, spec)
         return jax.make_array_from_callback(
@@ -424,18 +422,6 @@ class ShardedMatcher:
     # trace-time building blocks shared by the fused twin and the
     # split-phase kernels — one implementation, so parity can't drift
     # ------------------------------------------------------------------
-    def _smap(self):
-        """(shard_map, kwargs) — jax.shard_map landed post-0.4.x; older
-        jax ships it under experimental with check_rep instead of
-        check_vma."""
-        try:
-            smap = jax.shard_map
-            return smap, {"check_vma": False}
-        except AttributeError:
-            from jax.experimental.shard_map import shard_map as smap
-
-            return smap, {"check_rep": False}
-
     # -- AOT executable cache (docs/AOT.md) ----------------------------
     def attach_aot(self, client) -> None:
         """Attach an :class:`~swarm_tpu.aot.AotClient` so every
@@ -767,19 +753,18 @@ class ShardedMatcher:
                 status, rep, full,
             )
 
-        smap, smap_kwargs = self._smap()
         tab_specs, rep_specs, stream_spec, lengths_spec = self._specs(
             streams, lengths
         )
         out_specs = P("data") if full else (P("data"),) * 3
-        fn = smap(
+        fn = jax.shard_map(
             step,
             mesh=self.mesh,
             in_specs=(
                 tab_specs, rep_specs, stream_spec, lengths_spec, P("data"),
             ),
             out_specs=out_specs,
-            **smap_kwargs,
+            check_vma=False,
         )
         return self._wrap_jit(fn, f"sh.fused.full={full}")
 
@@ -865,7 +850,6 @@ class ShardedMatcher:
                 return cnt[None], overflow[None], nmax_out, streams_ext
             return cnt[None], overflow[None], nmax_out
 
-        smap, smap_kwargs = self._smap()
         tab_specs, _rep_specs, stream_spec, lengths_spec = self._specs(
             streams, lengths
         )
@@ -874,12 +858,12 @@ class ShardedMatcher:
         out_specs = (rank_spec, rank_spec, nmax_spec)
         if carry:
             out_specs = out_specs + ({k: P("data", "seq") for k in streams},)
-        fn = smap(
+        fn = jax.shard_map(
             step_a,
             mesh=self.mesh,
             in_specs=(tab_specs, stream_spec, lengths_spec),
             out_specs=out_specs,
-            **smap_kwargs,
+            check_vma=False,
         )
         # streams are donated into phase A only when the extended
         # views replace them as every later kernel's input (seq mesh +
@@ -940,19 +924,18 @@ class ShardedMatcher:
             )
             return value_bits[None], uncertain_bits[None]
 
-        smap, smap_kwargs = self._smap()
         tab_specs, rep_specs, stream_spec, lengths_spec = self._specs(
             streams, lengths
         )
         rank_spec = P(("model", "seq"), "data")
-        fn = smap(
+        fn = jax.shard_map(
             step_bp,
             mesh=self.mesh,
             in_specs=(
                 tab_specs, rep_specs, stream_spec, lengths_spec, rank_spec,
             ),
             out_specs=(rank_spec, rank_spec),
-            **smap_kwargs,
+            check_vma=False,
         )
         donate = (2, 4) if donate_streams else (4,)  # [streams,] cnt plane
         # kc rides the kernel id (it is baked into the step closure
@@ -994,7 +977,6 @@ class ShardedMatcher:
                 full_flag,
             )
 
-        smap, smap_kwargs = self._smap()
         rep_specs = jax.tree_util.tree_map(lambda _a: P(), self._rep_np)
         lengths_spec = {k: P("data") for k in lnames}
         rank_spec = P(("model", "seq"), "data")
@@ -1024,12 +1006,12 @@ class ShardedMatcher:
             donate = (3, 4, 5)
             if don_host:
                 donate = donate + (1, 2)
-        fn = smap(
+        fn = jax.shard_map(
             step_r,
             mesh=self.mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            **smap_kwargs,
+            check_vma=False,
         )
         return self._wrap_jit(
             fn,
@@ -1134,16 +1116,11 @@ class ShardedMatcher:
                 )
 
     def _stage(self, streams: dict, lengths: dict, status):
-        """Upload one batch through the dispatch staging pool: always a
-        COPY (plain ``jnp.asarray`` single-process, global jax.Arrays
-        spanning the mesh multi-process), so phase-B donation can never
-        corrupt caller-owned numpy — the engine's recycled encode
-        planes keep rotating untouched."""
-        if not self.multiprocess:
-            s_j, l_j, st_j, _staged = self.staging.stage(
-                streams, lengths, status
-            )
-            return s_j, l_j, st_j
+        """Upload one batch straight into its mesh layout (rows over
+        'data', bytes over 'seq': each device receives only its own
+        slice) — always a COPY, so phase-B donation can never corrupt
+        caller-owned numpy; the engine's recycled encode planes keep
+        rotating untouched."""
         s_j = {
             k: self._global(v, P("data", "seq")) for k, v in streams.items()
         }

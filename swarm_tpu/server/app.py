@@ -956,6 +956,13 @@ class SwarmServer:
         prefix, nodes = data.get("prefix"), data.get("nodes")
         if prefix is None or nodes is None:
             return self._json(400, {"message": "Both prefix and nodes are required"})
+        cap = self.fleet.capacity()
+        if cap is not None and int(nodes) > cap:
+            return self._json(
+                409,
+                {"message": f"{nodes} nodes requested; this host runs at "
+                 f"most {cap} (one worker per TPU chip)"},
+            )
         threading.Thread(
             target=self.fleet.spin_up, args=(prefix, int(nodes)), daemon=True
         ).start()

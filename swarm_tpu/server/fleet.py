@@ -65,9 +65,22 @@ def generate_node_names(prefix: str, nodes: int) -> list[str]:
     return [f"{prefix}{i}" for i in range(1, nodes + 1)]
 
 
+def local_chip_count() -> int:
+    """TPU chips this host exposes, counted from their device files
+    (``/dev/accel*``; ``/dev/vfio/<n>`` on newer generations) so the
+    server never touches JAX."""
+    import glob
+
+    return len(glob.glob("/dev/accel[0-9]*")) + len(glob.glob("/dev/vfio/[0-9]*"))
+
+
 class FleetProvider:
     def spin_up(self, prefix: str, nodes: int) -> None:
         raise NotImplementedError
+
+    def capacity(self) -> Optional[int]:
+        """Most nodes this provider can run at once (None = no bound)."""
+        return None
 
     def spin_down(self, prefix: str) -> None:
         raise NotImplementedError
@@ -99,9 +112,28 @@ class ProcessProvider(FleetProvider):
         self.extra_args = extra_args or []
         self._lock = threading.Lock()  # guards: _procs (reads)
         self._procs: dict[str, subprocess.Popen] = {}
+        # one worker per local chip: a chip belongs to one process, so a
+        # second worker on a one-chip host could never get a device.
+        # Unbounded when the workers run on the CPU (selected, or no chip)
+        chips = local_chip_count()
+        cpu = os.environ.get("JAX_PLATFORMS") == "cpu"
+        self._max_workers = None if cpu or not chips else chips
+
+    def capacity(self) -> Optional[int]:
+        return self._max_workers
 
     def spin_up(self, prefix, nodes):
-        for name in generate_node_names(prefix, nodes):
+        names = generate_node_names(prefix, nodes)
+        if self._max_workers is not None:
+            with self._lock:
+                live = {n for n, p in self._procs.items() if p.poll() is None}
+            if len(live | set(names)) > self._max_workers:
+                raise RuntimeError(
+                    f"{len(live | set(names))} local workers requested but "
+                    f"this host has {self._max_workers} TPU chip(s): one "
+                    "worker per chip"
+                )
+        for name in names:
             with self._lock:
                 if name in self._procs and self._procs[name].poll() is None:
                     continue

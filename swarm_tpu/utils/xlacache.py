@@ -16,7 +16,10 @@ import sys
 from pathlib import Path
 from typing import Optional
 
-DEFAULT_CACHE_DIR = "~/.cache/swarm_tpu/xla"
+#: where the cache lives when ``JAX_COMPILATION_CACHE_DIR`` is unset: one
+#: fixed, gitignored directory inside the checkout. The path is part of
+#: the cache key, so a directory that moved would never hit.
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".xla_cache"
 _active_dir: Optional[str] = None
 _metrics_installed = False
 
@@ -74,21 +77,26 @@ def install_cache_metrics() -> bool:
 
 
 def enable_compilation_cache(cache_dir: Optional[str] = None) -> str:
-    """Idempotently point JAX's persistent compilation cache at
-    ``cache_dir`` (default ``~/.cache/swarm_tpu/xla``, overridable via
-    ``SWARM_XLA_CACHE_DIR``; empty string disables). Returns the dir
-    actually in effect ('' when disabled) — once bound, later calls
-    with a different dir return the original binding. A cache dir that
-    cannot be created degrades to no-cache rather than failing startup
-    (the worker must run with a read-only HOME)."""
+    """Idempotently turn on JAX's persistent compilation cache. Returns
+    the dir in effect ('' when disabled); once bound, later calls
+    return the original binding.
+
+    The rule: ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache —
+    JAX reads it itself and this sets no other directory (empty
+    disables). Otherwise the cache goes to :data:`DEFAULT_CACHE_DIR`.
+    ``cache_dir`` is for tests that keep their own directory. A dir
+    that cannot be created degrades to no-cache rather than failing
+    startup (the worker must run from a read-only checkout)."""
     global _active_dir
     if _active_dir is not None:
         return _active_dir
-    raw = (
-        cache_dir
-        if cache_dir is not None
-        else os.environ.get("SWARM_XLA_CACHE_DIR", DEFAULT_CACHE_DIR)
-    )
+    import jax
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if cache_dir is None and env is not None:
+        raw = env  # JAX already bound it from the environment
+    else:
+        raw = str(DEFAULT_CACHE_DIR) if cache_dir is None else cache_dir
     if not raw:
         return ""
     path = Path(raw).expanduser()
@@ -98,13 +106,13 @@ def enable_compilation_cache(cache_dir: Optional[str] = None) -> str:
         # stderr: bench.py's stdout is a JSON-only metric stream
         print(f"xla cache disabled ({path}: {e})", file=sys.stderr)
         return ""
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", str(path))
+    if raw != env:
+        raw = str(path)
+        jax.config.update("jax_compilation_cache_dir", raw)
     # cache everything that took real compile time; tiny kernels
     # aren't worth the disk round-trip
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     install_cache_metrics()  # hit/miss counters ride every enable
-    _active_dir = str(path)
+    _active_dir = raw
     return _active_dir

@@ -724,6 +724,9 @@ class JobProcessor:
             ds.host_confirm_seconds,
             getattr(ds, "phase_a_seconds", 0.0),
             getattr(ds, "phase_b_seconds", 0.0),
+            ds.device_compile_seconds,
+            ds.device_faults,
+            ds.degraded_batches,
         )
 
     def _engine_perf_delta(self) -> dict:
@@ -732,12 +735,18 @@ class JobProcessor:
         mark = self._engine_stats_mark
         if mark is None:
             return {}
-        engine, rows0, dev0, confirm0, pa0, pb0 = mark
+        engine, rows0, dev0, confirm0, pa0, pb0, comp0, faults0, degr0 = mark
         ds = engine.stats
         out = {
             "rows": ds.rows - rows0,
             "device_s": round(ds.device_seconds - dev0, 6),
             "host_confirm_s": round(ds.host_confirm_seconds - confirm0, 6),
+            "compile_s": round(ds.device_compile_seconds - comp0, 6),
+            # device-degraded mode (docs/RESILIENCE.md): batches this
+            # job served from the CPU oracle instead of the device
+            "device_faults": ds.device_faults - faults0,
+            "degraded_batches": ds.degraded_batches - degr0,
+            **device_perf(),
         }
         # split-phase device attribution, when the matcher reported it
         # (single-device compacted path); feeds the device.phase_a/b
@@ -1207,6 +1216,7 @@ class JobProcessor:
         if classifier is None:
             classifier = ServiceClassifier(db_path=module.raw.get("probes_db"))
             self._engines[key] = classifier
+        self._mark_engine_stats(classifier.engine)
         rows, sent = ProbeExecutor(module.probe).run_service(
             data.decode("utf-8", "surrogateescape").splitlines(), classifier
         )
@@ -1217,6 +1227,38 @@ class JobProcessor:
             return formats.format_nmap_report(infos).encode()
         lines = [info.line() for info in infos if info.open]
         return ("\n".join(lines) + "\n").encode() if lines else b""
+
+
+def device_info() -> dict:
+    """The accelerator this worker runs on, as JAX reports it."""
+    import jax
+
+    devs = jax.devices()
+    return {
+        "platform": devs[0].platform,
+        "kind": devs[0].device_kind,
+        "count": len(devs),
+    }
+
+
+def device_perf() -> dict:
+    """Job perf fields that say where the job ran: the device, the
+    persistent compile cache's process-lifetime hit/miss counts, and
+    the first device's peak memory (backends that report it)."""
+    import jax
+
+    from swarm_tpu.utils.xlacache import _cache_counters
+
+    hit, miss = _cache_counters()
+    out = {
+        "device": device_info(),
+        "xla_cache_hit": int(hit.labels().value),
+        "xla_cache_miss": int(miss.labels().value),
+    }
+    stats = jax.devices()[0].memory_stats() or {}
+    if "peak_bytes_in_use" in stats:
+        out["peak_bytes_in_use"] = int(stats["peak_bytes_in_use"])
+    return out
 
 
 def main(argv: Optional[list[str]] = None) -> None:
@@ -1238,11 +1280,6 @@ def main(argv: Optional[list[str]] = None) -> None:
         modules_dir=args.modules_dir,
         max_jobs=args.max_jobs,
     )
-    # An operator-set JAX_PLATFORMS env must actually stick: site-hook
-    # platform plugins can override the env var alone (see utils/jaxpin)
-    from swarm_tpu.utils.jaxpin import pin_platform_from_env
-
-    pin_platform_from_env()
     # multi-host worker: join the DCN process group when configured
     # (SWARM_COORDINATOR/-NUM_PROCESSES/-PROCESS_ID) so the tpu
     # backend's mesh spans every host's chips; no-op single-host
@@ -1260,6 +1297,7 @@ def main(argv: Optional[list[str]] = None) -> None:
     install_cache_metrics()
     if maybe_initialize_distributed():
         print("multi-host: jax.distributed initialized")
+    print("worker device: " + json.dumps(device_info()), flush=True)
     proc = JobProcessor(cfg)
     # SIGTERM routes through the DRAIN path, not a mid-upload death
     # (docs/RESILIENCE.md §Preemption): the handler only sets a flag,

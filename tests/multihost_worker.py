@@ -67,20 +67,13 @@ def probe() -> None:
 
     devices = np.array(jax.devices())
     mesh = Mesh(devices, ("data",))
-    try:
-        smap = jax.shard_map
-        kw = {"check_vma": False}
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as smap
-
-        kw = {"check_rep": False}
     fn = jax.jit(
-        smap(
+        jax.shard_map(
             lambda x: jax.lax.psum(x.sum(), "data"),
             mesh=mesh,
             in_specs=P("data"),
             out_specs=P(),
-            **kw,
+            check_vma=False,
         )
     )
     arr = np.ones((len(devices),), dtype=np.int32)

@@ -11,6 +11,8 @@ import subprocess
 import sys
 from pathlib import Path
 
+import pytest
+
 import bench
 
 
@@ -138,23 +140,22 @@ def _fake_phase_output(phase: str) -> str:
 
 
 def test_main_emits_exact_headline_last(monkeypatch, capsys):
+    cmds = []
+
     def fake_run(cmd, **kw):
+        cmds.append(cmd)
         phase = cmd[-1]
         return subprocess.CompletedProcess(
             cmd, 0, stdout=_fake_phase_output(phase)
         )
 
     monkeypatch.setattr(subprocess, "run", fake_run)
-    # the parent pre-probe (round-5 outage-retry) probes the backend
-    # before the phase loop — stub it so this test stays device-free
-    from swarm_tpu.utils import backendprobe
-
-    monkeypatch.setattr(
-        backendprobe, "probe_backend", lambda timeout: (True, "cpu", 1)
-    )
     monkeypatch.setattr(sys, "argv", ["bench.py"])
     rc = bench.main()
     assert rc == 0
+    # the parent starts nothing but its phase children, one per phase
+    # (no device probe of its own: it never touches JAX)
+    assert [c[-2:] for c in cmds] == [["--phase", ph] for ph in bench.PHASES]
     out = [
         json.loads(s)
         for s in capsys.readouterr().out.splitlines()
@@ -185,11 +186,6 @@ def test_main_headline_survives_aux_phase_failure(monkeypatch, capsys):
         )
 
     monkeypatch.setattr(subprocess, "run", fake_run)
-    from swarm_tpu.utils import backendprobe
-
-    monkeypatch.setattr(
-        backendprobe, "probe_backend", lambda timeout: (True, "cpu", 1)
-    )
     monkeypatch.setattr(sys, "argv", ["bench.py"])
     rc = bench.main()
     assert rc == 1  # failure reported in the exit code
@@ -199,3 +195,35 @@ def test_main_headline_survives_aux_phase_failure(monkeypatch, capsys):
         if s.strip().startswith("{")
     ]
     assert out[-1]["metric"] == "exact_fingerprints_per_sec_per_chip"
+
+
+def test_resolve_device_fails_without_tpu_unless_cpu_selected(monkeypatch):
+    """No TPU is a failure; the CPU serves only when JAX_PLATFORMS=cpu
+    selects it explicitly, and then every emitted line names it."""
+    monkeypatch.setattr(bench, "_DEVICE", {})
+    monkeypatch.setenv("JAX_PLATFORMS", "")
+    with pytest.raises(SystemExit, match="no TPU"):
+        bench.resolve_device()
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    dev = bench.resolve_device()
+    assert dev.platform == "cpu"
+    assert bench._DEVICE["platform"] == "cpu"
+    assert bench._DEVICE["count"] >= 1
+
+
+def test_synthesized_speedup_names_the_exact_childs_device(monkeypatch, capsys):
+    dev = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+
+    def fake_run(cmd, **kw):
+        lines = _fake_phase_output(cmd[-1]).splitlines()
+        out = "".join(json.dumps({**json.loads(x), "device": dev}) + "\n" for x in lines)
+        return subprocess.CompletedProcess(cmd, 0, stdout=out)
+
+    monkeypatch.setattr(bench, "_DEVICE", {})
+    monkeypatch.setattr(subprocess, "run", fake_run)
+    monkeypatch.setattr(sys, "argv", ["bench.py"])
+    assert bench.main() == 0
+    out = [json.loads(s) for s in capsys.readouterr().out.splitlines()
+           if s.strip().startswith("{")]
+    speedup = [r for r in out if r["metric"] == "device_vs_cpu_oracle_speedup"]
+    assert speedup and speedup[0]["device"] == dev

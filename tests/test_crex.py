@@ -437,9 +437,6 @@ def test_abi_handshake_refuses_stale_library(monkeypatch):
     monkeypatch.setattr(ncrex, "_lib", None)
     monkeypatch.setattr(ncrex, "_lib_failed", False)
     monkeypatch.setattr(ncrex.ctypes, "CDLL", lambda path: _StaleLib())
-    monkeypatch.setattr(
-        ncrex.subprocess, "run", lambda *a, **k: None
-    )
     assert ncrex.ensure_crex() is None
     assert ncrex._lib_failed
 

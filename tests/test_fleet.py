@@ -11,6 +11,8 @@ layer — no egress.
 import threading
 import time
 
+import pytest
+
 from swarm_tpu.config import Config
 from swarm_tpu.server.fleet import (
     AutoscaleAdvisor,
@@ -164,6 +166,25 @@ def test_process_provider_lifecycle(tmp_path):
         assert p.list_nodes("pw") == []
     finally:
         p.shutdown()
+
+
+def test_process_provider_one_worker_per_chip(monkeypatch):
+    """A chip belongs to one process: on a one-chip host a second local
+    worker is refused with a clear error before anything spawns, and
+    the CPU (selected explicitly) stays unbounded."""
+    from swarm_tpu.server import fleet as fleet_mod
+
+    cfg = Config(fleet_provider="process", server_url="http://127.0.0.1:1",
+                 api_key="k")
+    monkeypatch.setattr(fleet_mod, "local_chip_count", lambda: 1)
+    monkeypatch.setenv("JAX_PLATFORMS", "")
+    p = ProcessProvider(cfg)
+    assert p.capacity() == 1
+    with pytest.raises(RuntimeError, match="one worker per chip"):
+        p.spin_up("pw", 2)
+    assert p.list_nodes("pw") == []
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert ProcessProvider(cfg).capacity() is None
 
 
 def test_idle_teardown_via_queue():

@@ -11,6 +11,7 @@ import sys
 from pathlib import Path
 
 import jax
+import pytest
 
 sys.path.insert(0, str(Path(__file__).parent.parent))
 
@@ -34,3 +35,11 @@ def test_dryrun_multichip_runs_in_process(capsys):
     graft.dryrun_multichip(8)
     out = capsys.readouterr().out
     assert "dryrun_multichip:" in out and "ok" in out
+
+
+def test_dryrun_multichip_fails_short_of_devices_unless_cpu_selected(monkeypatch):
+    """Too few devices is an error off the CPU: no silent move to a
+    virtual CPU mesh on a chip host."""
+    monkeypatch.setenv("JAX_PLATFORMS", "")
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        graft.dryrun_multichip(64)

@@ -192,3 +192,29 @@ def test_ip_roundtrip():
     assert arr.dtype == np.uint32
     # network byte order: first octet in the low byte on little-endian
     assert struct.pack("=I", int(arr[0]))[0] == 10
+
+
+@pytest.mark.parametrize("loader", ["scanio", "crex"])
+def test_failed_native_build_raises(monkeypatch, loader):
+    """A build from the committed sources that fails raises: a library
+    some other build left on disk must never load in its place."""
+    import subprocess
+
+    from swarm_tpu.native import crex as ncrex
+    from swarm_tpu.native import scanio
+
+    monkeypatch.setattr(
+        scanio.subprocess, "run",
+        lambda cmd, **kw: subprocess.CompletedProcess(cmd, 2, "", "boom"),
+    )
+    if loader == "scanio":
+        monkeypatch.setattr(scanio, "_lib", None)
+        ensure = scanio.ensure_lib
+    else:
+        monkeypatch.setattr(ncrex, "_lib", None)
+        monkeypatch.setattr(ncrex, "_lib_failed", False)
+        monkeypatch.setattr(ncrex, "_DIR_OVERRIDDEN", False)
+        ensure = ncrex.ensure_crex
+    monkeypatch.setattr(scanio, "_DIR_OVERRIDDEN", False)
+    with pytest.raises(RuntimeError, match="native build failed"):
+        ensure()

@@ -9,6 +9,9 @@ trace ID (telemetry PR)."""
 
 import json
 import time
+from pathlib import Path
+
+import pytest
 
 from swarm_tpu.datamodel import Job, JobStatus, rollup_scans
 from swarm_tpu.utils.trace import PhaseTimer, maybe_device_profile
@@ -204,3 +207,70 @@ def test_compilation_cache_enable(tmp_path, monkeypatch):
     blocker = tmp_path / "blocker"
     blocker.write_text("")  # a file where a dir is needed
     assert xlacache.enable_compilation_cache(str(blocker / "sub")) == ""
+
+
+# The compile-cache rule (utils/xlacache.py): JAX_COMPILATION_CACHE_DIR,
+# when set, is the cache; otherwise one fixed gitignored directory in
+# the checkout. Fresh processes: JAX reads the variable at import.
+_CACHE_PROBE = (
+    "import sys, jax; sys.path.insert(0, {repo!r}); {setup}; "
+    "print(jax.config.jax_compilation_cache_dir)"
+)
+
+
+def _cache_dir_in_child(setup: str, env_dir=None) -> str:
+    import os
+    import subprocess
+    import sys
+
+    repo = str(Path(__file__).resolve().parent.parent)
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    r = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE.format(repo=repo, setup=setup)],
+        cwd=repo, env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert r.returncode == 0, r.stderr[-2000:]
+    return r.stdout.strip().splitlines()[-1]
+
+
+@pytest.mark.parametrize("setup", [
+    "from swarm_tpu.utils.xlacache import enable_compilation_cache; "
+    "enable_compilation_cache()",
+    "import bench; bench.resolve_device()",
+])
+def test_compilation_cache_env_var_wins(tmp_path, setup):
+    """bench and the worker (both enable_compilation_cache) use the
+    environment's directory and set no other."""
+    want = str(tmp_path / "jcc")
+    assert _cache_dir_in_child(setup, env_dir=want) == want
+
+
+def test_compilation_cache_default_is_fixed_dir_in_checkout():
+    from swarm_tpu.utils import xlacache
+
+    got = _cache_dir_in_child(
+        "from swarm_tpu.utils.xlacache import enable_compilation_cache; "
+        "enable_compilation_cache()"
+    )
+    repo = Path(__file__).resolve().parent.parent
+    assert got == str(xlacache.DEFAULT_CACHE_DIR) == str(repo / ".xla_cache")
+    assert ".xla_cache/" in (repo / ".gitignore").read_text().splitlines()
+
+
+def test_only_xlacache_sets_a_cache_directory():
+    """No second name, no second setter outside the tests."""
+    repo = Path(__file__).resolve().parent.parent
+    files = [repo / "bench.py", repo / "chip_smoke.py", repo / "__graft_entry__.py"]
+    files += sorted((repo / "swarm_tpu").rglob("*.py"))
+    files += sorted((repo / "tools").rglob("*.py"))
+    setters = [
+        f.relative_to(repo).as_posix()
+        for f in files
+        if any(k in f.read_text() for k in (
+            '"jax_compilation_cache_dir"', "set_cache_dir(", "SWARM_XLA_CACHE_DIR"
+        ))
+    ]
+    assert setters == ["swarm_tpu/utils/xlacache.py"]
