@@ -2068,10 +2068,11 @@ def _trace_latency_breakdown(
     short traced run — one bulk flood plus interactive probes against
     a real server + worker — whose assembled waterfalls are reduced to
     per-segment medians (queue-wait / sched / download / execute /
-    device / walk / upload, ms) per class. Runs SEPARATELY from the
-    timed A/B arms on purpose: the headline latency numbers stay
-    tracing-free, and this run answers the follow-up question those
-    numbers raise ("where does the interactive p99 actually go?")."""
+    device.launch / device.result_wait / engine.confirm_walk / upload,
+    ms) per class. Runs SEPARATELY from the timed A/B arms on purpose:
+    the headline latency numbers stay tracing-free, and this run
+    answers the follow-up question those numbers raise ("where does
+    the interactive p99 actually go?")."""
     import statistics
 
     from swarm_tpu.telemetry import tracing
@@ -2105,7 +2106,8 @@ def _trace_latency_breakdown(
             ids["bulk"] + ids["interactive"], deadline_s=300
         )
         segs = ("queue-wait", "sched", "download", "execute",
-                "device", "walk", "upload")
+                "device.launch", "device.result_wait",
+                "engine.confirm_walk", "upload")
         out: dict = {
             "all_complete": bool(done),
             "codes_ok": all(c == 200 for c in codes),

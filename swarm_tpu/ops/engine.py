@@ -38,6 +38,7 @@ from swarm_tpu.fingerprints.model import Response, Template
 from swarm_tpu.ops import cpu_ref, fastre
 from swarm_tpu.ops.encoding import _RotatingPool, encode_batch, round_up
 from swarm_tpu.ops.match import DeviceDB
+from swarm_tpu.telemetry import tracing
 from swarm_tpu.telemetry.memo_export import L1_HITS, L1_MISSES
 
 
@@ -106,14 +107,6 @@ class EngineStats:
     # LOADED a published executable instead of compiling it
     device_fetch_seconds: float = 0.0
     device_fetches: int = 0
-    # split-phase device attribution (ops/match.py dispatch): phase A is
-    # the wall up to the survivor-scalar sync, the remainder of the
-    # device wall is phase B + transfer. Populated on the single-device
-    # compacted path only (0.0 elsewhere); both are included in
-    # device_seconds. The worker folds these into device.phase_a /
-    # device.phase_b child spans (docs/OBSERVABILITY.md §Tracing).
-    phase_a_seconds: float = 0.0
-    phase_b_seconds: float = 0.0
     host_confirm_seconds: float = 0.0
     host_confirm_pairs: int = 0
     host_always_pairs: int = 0
@@ -866,13 +859,6 @@ class MatchEngine:
                         fast_acc[bt] = acc = []
                     acc.extend(chunks)
 
-        import time as _time
-
-        _dbg = _os.environ.get("SWARM_EXT_DEBUG")
-        if _dbg:
-            _tA = _time.perf_counter()
-            print(f"    extA hits={len(pending)} keys={len(fills)} "
-                  f"tasks={len(tasks)} segs={len(segs)}", flush=True)
         done: dict = {}
         if fills:
             failed: set = set()
@@ -894,11 +880,6 @@ class MatchEngine:
                     for (_pat, group), t in task_list
                 ]
             for ((pattern, group), t), res in zip(task_list, results):
-                if _dbg:
-                    nsp = sum(len(s) for s in res if s) if res else -1
-                    print(f"    extB {pattern[:40]!r} items="
-                          f"{len(t['parts'])} spans={nsp} "
-                          f"none={res is None}", flush=True)
                 if res is None:
                     failed.update(k for k, _p in t["items"])
                     continue
@@ -924,9 +905,6 @@ class MatchEngine:
                 self._cache_put(cache, key, vals)
                 done[key] = vals
 
-        if _dbg:
-            print(f"    extC batchcalls {_time.perf_counter()-_tA:.4f}s "
-                  f"failed={len(failed) if fills else 0}", flush=True)
         for bt, seg in segs.items():
             vals = []
             for kind, v in seg:
@@ -1198,6 +1176,7 @@ class MatchEngine:
             out.extend(self.rowmatches_from_packed(packed, len(rows)))
         return out
 
+    @tracing.spanned("engine.assemble")
     def rowmatches_from_packed(self, packed: PackedMatches, n: int) -> list:
         """Per-row RowMatches assembly from one PackedMatches — THE
         single assembly used by both :meth:`match` and the scheduler
@@ -1277,7 +1256,8 @@ class MatchEngine:
         with ThreadPoolExecutor(max_workers=1) as pool:
             fut = pool.submit(self.encode_packed, chunks[0], True)
             for i, c in enumerate(chunks):
-                pre = fut.result()
+                with tracing.span("engine.encode_wait"):
+                    pre = fut.result()
                 if i + 1 < len(chunks):
                     fut = pool.submit(self.encode_packed, chunks[i + 1], True)
                 yield c, pre
@@ -1307,6 +1287,7 @@ class MatchEngine:
         self._backend_ready = True
 
     # ------------------------------------------------------------------
+    @tracing.spanned("engine.encode_chunk")
     def encode_packed(self, rows: Sequence[Response], reuse_buffers: bool = False):
         """Public pre-encode for pipelined feeding: callers may encode
         batch i+1 on another thread while the device matches batch i
@@ -1573,19 +1554,6 @@ class MatchEngine:
         ]
         rows = next(iter(streams.values())).shape[0] if streams else 0
         return f"r{rows}." + ".".join(parts)
-
-    def _note_phase_split(self, matcher, dt: float) -> None:
-        """Attribute one device interval to phase A/B from the
-        matcher's per-dispatch ``last_compact["phase_a_s"]`` marker
-        (popped — exactly one consumer per dispatch, so a later
-        non-compacted or failed dispatch can't replay a stale split)."""
-        # requires-lock: _stats_lock
-        lc = getattr(matcher, "last_compact", None)
-        pa = lc.pop("phase_a_s", None) if isinstance(lc, dict) else None
-        if isinstance(pa, (int, float)) and pa > 0:
-            pa = min(float(pa), dt)
-            self.stats.phase_a_seconds += pa
-            self.stats.phase_b_seconds += max(0.0, dt - pa)
 
     def _note_device_fault(self, breaker, exc: BaseException) -> None:
         # under the scheduler's walk offload this runs on the submit
@@ -2188,9 +2156,7 @@ class MatchEngine:
                 np.ascontiguousarray(_rows_view(p)) for p in wf_planes
             )
         with self._stats_lock:
-            dt_dev = time.perf_counter() - t0
-            self.stats.device_seconds += dt_dev
-            self._note_phase_split(matcher, dt_dev)
+            self.stats.device_seconds += time.perf_counter() - t0
         # compile-time attribution rides the matcher's counters (the
         # sharded matcher carries the same spy fields per mesh shape)
         self.stats.device_compile_seconds = getattr(
@@ -2206,296 +2172,299 @@ class MatchEngine:
         row_redo = overflow | _rows_view(batch.truncated)
         self.stats.overflow_rows += int(row_redo.sum())
 
-        t1 = time.perf_counter()
-        confirms: dict = {}
-        op_cache: dict = {}  # (b, op_id) -> exact bool
-        # precomputed verdict planes from the row-parallel batched
-        # confirm (docs/HOST_WALK.md): filled after the redo pass below,
-        # consulted first by confirm_matcher/resolve_op. The resolution
-        # structure (loops, short-circuits, counting) is untouched —
-        # only where a pair's verdict COMES FROM changes, so verdicts
-        # and host_confirm_pairs stay bit-identical to the serial walk.
-        pre_m: dict = {}   # (b, m_id) -> exact bool
-        pre_op: dict = {}  # (b, op_id) -> exact bool (prefiltered ops)
+        # the host half: sparse resolution over the planes, now host
+        # arrays of B rows
+        with tracing.span("engine.confirm_walk"):
+            t1 = time.perf_counter()
+            confirms: dict = {}
+            op_cache: dict = {}  # (b, op_id) -> exact bool
+            # precomputed verdict planes from the row-parallel batched
+            # confirm (docs/HOST_WALK.md): filled after the redo pass below,
+            # consulted first by confirm_matcher/resolve_op. The resolution
+            # structure (loops, short-circuits, counting) is untouched —
+            # only where a pair's verdict COMES FROM changes, so verdicts
+            # and host_confirm_pairs stay bit-identical to the serial walk.
+            pre_m: dict = {}   # (b, m_id) -> exact bool
+            pre_op: dict = {}  # (b, op_id) -> exact bool (prefiltered ops)
 
-        def confirm_matcher(b: int, m_id: int, row: Response) -> bool:
-            v = pre_m.get((b, m_id))
-            if v is not None:
-                return v
-            return self._confirm_matcher_serial(m_id, row)
+            def confirm_matcher(b: int, m_id: int, row: Response) -> bool:
+                v = pre_m.get((b, m_id))
+                if v is not None:
+                    return v
+                return self._confirm_matcher_serial(m_id, row)
 
-        op_prefilter = self._op_prefilter_py
-        op_cond_and = self._op_cond_and_py
+            op_prefilter = self._op_prefilter_py
+            op_cond_and = self._op_cond_and_py
 
-        def resolve_op(b: int, op_id: int, row: Response) -> bool:
-            key = (b, op_id)
-            v = op_cache.get(key)
-            if v is not None:
-                return v
-            if not _bit(pop_unc, b, op_id):
-                v = _bit(pop_value, b, op_id)
-            elif op_prefilter[op_id]:
-                # superset-lowered op: per-matcher bits are weakened, so
-                # fired rows re-run the whole op (prefiltered + cached
-                # per matcher — semantics identical to the oracle's
-                # match_operation); the batched walk may have resolved
-                # it already
-                v = pre_op.get(key)
-                if v is None:
-                    v = self._confirm_operation(self._op_obj[op_id], row)
-                confirms[b] = confirms.get(b, 0) + 1
-                self.stats.host_confirm_pairs += 1
-            else:
-                # undecided ⇒ certain matchers are neutral; combine the
-                # uncertain ones' exact values under the op condition
-                ids = self._op_m_arr[op_id]
-                bits = (
-                    pm_unc[b, self._op_m_bytes[op_id]]
-                    >> self._op_m_shift[op_id]
-                ) & 1
-                vals = [
-                    confirm_matcher(b, int(m_id), row)
-                    for m_id in ids[bits.astype(bool)]
-                ]
-                confirms[b] = confirms.get(b, 0) + len(vals)
-                self.stats.host_confirm_pairs += len(vals)
-                v = all(vals) if op_cond_and[op_id] else any(vals)
-            op_cache[key] = v
-            return v
-
-        rowdep = self._rowdep_t
-        # (unique slot, t_idx) pairs whose verdict must be decided per
-        # MEMBER row (row-dependent template went device-undecided)
-        deferred: list = []
-
-        # --- full-row redo (rare): the oracle end to end, extractions
-        # included (the extraction pass below skips these rows).
-        # Content-independent templates run once on the representative;
-        # row-dependent ones run per member in the fixup pass below ---
-        redo_rows = np.flatnonzero(row_redo)
-        uredo_extractions: dict = {}  # (new-subset pos, tid) -> values
-        for b in redo_rows.tolist():
-            row = nrows[b]
-            rowbits = np.zeros((pt_value.shape[1],), dtype=np.uint8)
-            for t_idx, template in enumerate(db.templates):
-                if t_idx in rowdep:
-                    deferred.append((b, t_idx))
-                    continue
-                res_matched, res_ext = self._redo_template(template, row)
-                confirms[b] = confirms.get(b, 0) + 1
-                self.stats.host_confirm_pairs += 1
-                if res_matched:
-                    rowbits[t_idx >> 3] |= 0x80 >> (t_idx & 7)
-                    if res_ext:
-                        uredo_extractions[(b, template.id)] = res_ext
-            pt_value[b] = rowbits
-
-        # --- sparse uncertainty resolution (unique plane) ---
-        t_unc = time.perf_counter()
-        use_native = self._use_native_memo()
-        # row-parallel batched confirm (docs/HOST_WALK.md): resolve the
-        # whole batch's pending (row, matcher) pairs with grouped
-        # GIL-released native passes BEFORE the serial-structured loops
-        # below consume them. walk_threads=0 keeps the reference walk.
-        if use_native and not row_redo.all() and self.walk_threads > 0:
-            pre_m, pre_op = self._precompute_confirms(
-                nrows, pt_value, pt_unc, pop_value, pop_unc, pm_unc,
-                set(redo_rows.tolist()),
-            )
-        # (b, t_idx) pairs whose verdict is decided by the extraction
-        # pass below (pseudo-ext templates on the native path)
-        pseudo_pending: list = []
-        if not row_redo.all():
-            skip = set(redo_rows.tolist())
-            if use_native:
-                from swarm_tpu.native.scanio import plane_bits
-
-                ub, ut = plane_bits(np.ascontiguousarray(pt_unc), NT)
-                pairs = zip(ub.tolist(), ut.tolist())
-            else:
-                pairs = (
-                    (b, byte_i * 8 + k)
-                    for b, byte_i in np.argwhere(pt_unc).tolist()
-                    for k in range(8)
-                    if (int(pt_unc[b, byte_i]) & (0x80 >> k))
-                    and byte_i * 8 + k < NT
-                )
-            pseudo_t = self._pseudo_t
-            for b, t_idx in pairs:
-                if b in skip:
-                    continue
-                byte_i = t_idx >> 3
-                mask = 0x80 >> (t_idx & 7)
-                if (
-                    use_native
-                    and t_idx in pseudo_t
-                    and t_idx not in rowdep
-                ):
-                    # verdict == extraction non-emptiness: decided by
-                    # the batched extraction pass (bit set there on
-                    # extraction); per-pair confirm calls cost ~10x
-                    # the batched native scan at walk rates
-                    pseudo_pending.append((b, t_idx))
-                    pt_value[b, byte_i] &= 0xFF ^ mask
-                    continue
-                row = nrows[b]
-                if t_idx in rowdep:
-                    # undecided row-dependent template: content-
-                    # identical rows can disagree here — decide per
-                    # member below; clear the broadcast bit
-                    deferred.append((b, t_idx))
-                    pt_value[b, byte_i] &= 0xFF ^ mask
-                    continue
-                # undecided ⇒ no certain-true op; OR over the
-                # uncertain ops' exact values decides the template
-                hit = False
-                for op_id in self._t_ops_py[t_idx]:
-                    if _bit(pop_unc, b, op_id) and resolve_op(
-                        b, op_id, row
-                    ):
-                        hit = True
-                        break
-                if hit:
-                    pt_value[b, byte_i] |= mask
+            def resolve_op(b: int, op_id: int, row: Response) -> bool:
+                key = (b, op_id)
+                v = op_cache.get(key)
+                if v is not None:
+                    return v
+                if not _bit(pop_unc, b, op_id):
+                    v = _bit(pop_value, b, op_id)
+                elif op_prefilter[op_id]:
+                    # superset-lowered op: per-matcher bits are weakened, so
+                    # fired rows re-run the whole op (prefiltered + cached
+                    # per matcher — semantics identical to the oracle's
+                    # match_operation); the batched walk may have resolved
+                    # it already
+                    v = pre_op.get(key)
+                    if v is None:
+                        v = self._confirm_operation(self._op_obj[op_id], row)
+                    confirms[b] = confirms.get(b, 0) + 1
+                    self.stats.host_confirm_pairs += 1
                 else:
-                    pt_value[b, byte_i] &= 0xFF ^ mask
-
-        # --- extraction pass (unique plane): only extractor templates,
-        # only hit rows (one vectorized gather over all extractor
-        # columns at once — a Python loop over ~600 extractor templates
-        # costs more than the actual extractions). Row-dependent
-        # templates are handled in the member fixup pass ---
-        t_ext = time.perf_counter()
-        self.stats.unc_seconds += t_ext - t_unc
-        uextractions: dict = dict(uredo_extractions)
-        redo_set = set(redo_rows.tolist())
-        if len(self._ext_cols):
-            emask = self._ext_byte_mask
-            masked = pt_value[:, : len(emask)] & emask[None, :]
-            tids = db.template_ids
-            if self._use_native_memo():
-                # one C pass enumerates the extractor-plane hits AND
-                # resolves op certainty against the packed planes —
-                # Python touches only ops that are certainly-true
-                # (extract) or undecided (resolve_op), in the same
-                # (b-major, t, op) order the Python loop used
-                from swarm_tpu.native.scanio import ext_resolve
-
-                skip_rows = np.zeros(len(nrows), dtype=np.uint8)
-                for rb in redo_set:
-                    skip_rows[rb] = 1
-                bs, ts, opsv, sts = ext_resolve(
-                    masked, NT, self._rowdep_mask, skip_rows,
-                    self._t_ops_indptr, self._t_ops_flat,
-                    np.ascontiguousarray(pop_value),
-                    np.ascontiguousarray(pop_unc),
-                )
-                t_sub = time.perf_counter()
-                self.stats.ext_enum_seconds += t_sub - t_ext
-                # certainty resolution stays in (b-major, t, op) order;
-                # the regex extractions themselves then run BATCHED —
-                # one native dispatch per distinct pattern over every
-                # pending content (per-call overhead dominated the
-                # fresh-content walk at per-hit rates)
-                pending: list = []
-                for b, t_idx, op_id, st in zip(
-                    bs.tolist(), ts.tolist(), opsv.tolist(), sts.tolist()
-                ):
-                    if st == 2 and not resolve_op(b, op_id, nrows[b]):
-                        continue
-                    pending.append((b, t_idx, op_id))
-                # deferred pseudo-ext verdicts ride the same batch:
-                # each uncertain op with >= 1 live pattern joins the
-                # pending list; its (b, t) verdict bit is set below
-                # iff the batched extraction produced values
-                pseudo_set = set()
-                for b, t_idx in pseudo_pending:
-                    pseudo_set.add((b, t_idx))
-                    for op_id in self._t_ops_py[t_idx]:
-                        if _bit(pop_unc, b, op_id):
-                            pending.append((b, t_idx, op_id))
-                if pseudo_pending:
-                    self.stats.host_confirm_pairs += len(pseudo_pending)
-                    for b, _t in pseudo_pending:
-                        confirms[b] = confirms.get(b, 0) + 1
-                # live-pattern hints for per-pattern extraction
-                # prefilters: the device pm-uncertainty bits already
-                # say WHICH patterns' literals occurred — the
-                # extraction pass then skips every other pattern with
-                # no host scanning at all (certain-false bits are an
-                # exact no-match proof)
-                # hints are {ex_local: [p_idx, ...]} with only LIVE
-                # patterns (flatnonzero is ascending and matcher order
-                # is (ex_local, p_idx)-ascending, so lists stay in
-                # pattern order) — consumers never touch the op's full
-                # pattern population. The pm-plane gather batches per
-                # op across all its pending rows: one 2D fancy-index
-                # instead of a per-(row, op) 689-element gather.
-                hints: dict = {}
-                by_op: dict = {}
-                for b, _t_idx, op_id in pending:
-                    if op_id in self._op_ext_pats:
-                        by_op.setdefault(op_id, set()).add(b)
-                for op_id, bset in by_op.items():
-                    rows_ = sorted(bset)
-                    bits2 = (
-                        pm_unc[np.ix_(rows_, self._op_m_bytes[op_id])]
-                        >> self._op_m_shift[op_id][None, :]
+                    # undecided ⇒ certain matchers are neutral; combine the
+                    # uncertain ones' exact values under the op condition
+                    ids = self._op_m_arr[op_id]
+                    bits = (
+                        pm_unc[b, self._op_m_bytes[op_id]]
+                        >> self._op_m_shift[op_id]
                     ) & 1
-                    pats = self._op_ext_pats[op_id]
-                    for b in rows_:
-                        hints[(b, op_id)] = {}
-                    # one nonzero over the whole (rows × matchers)
-                    # plane instead of a per-row flatnonzero
-                    ris, ks = np.nonzero(bits2)
-                    for ri, k in zip(ris.tolist(), ks.tolist()):
-                        el, pi = pats[k]
-                        hints[(rows_[ri], op_id)].setdefault(
-                            el, []
-                        ).append(pi)
-                t_sub2 = time.perf_counter()
-                self.stats.ext_resolve_seconds += t_sub2 - t_sub
-                for (b, t_idx), vals in self._extract_pending(
-                    pending, nrows, hints
-                ).items():
-                    if vals:
-                        uextractions[(b, tids[t_idx])] = vals
-                        if (b, t_idx) in pseudo_set:
-                            # fused verdict: extraction fired
-                            pt_value[b, t_idx >> 3] |= 0x80 >> (t_idx & 7)
-                self.stats.ext_extract_seconds += (
-                    time.perf_counter() - t_sub2
-                )
-            else:
-                hit_b, hit_t = np.nonzero(
-                    np.unpackbits(masked, axis=1, count=NT)
-                )
-                t_ops = self._t_ops_py
-                for b, t_idx in zip(hit_b.tolist(), hit_t.tolist()):
-                    if b in redo_set:
-                        continue  # oracle already extracted above
+                    vals = [
+                        confirm_matcher(b, int(m_id), row)
+                        for m_id in ids[bits.astype(bool)]
+                    ]
+                    confirms[b] = confirms.get(b, 0) + len(vals)
+                    self.stats.host_confirm_pairs += len(vals)
+                    v = all(vals) if op_cond_and[op_id] else any(vals)
+                op_cache[key] = v
+                return v
+
+            rowdep = self._rowdep_t
+            # (unique slot, t_idx) pairs whose verdict must be decided per
+            # MEMBER row (row-dependent template went device-undecided)
+            deferred: list = []
+
+            # --- full-row redo (rare): the oracle end to end, extractions
+            # included (the extraction pass below skips these rows).
+            # Content-independent templates run once on the representative;
+            # row-dependent ones run per member in the fixup pass below ---
+            redo_rows = np.flatnonzero(row_redo)
+            uredo_extractions: dict = {}  # (new-subset pos, tid) -> values
+            for b in redo_rows.tolist():
+                row = nrows[b]
+                rowbits = np.zeros((pt_value.shape[1],), dtype=np.uint8)
+                for t_idx, template in enumerate(db.templates):
                     if t_idx in rowdep:
+                        deferred.append((b, t_idx))
+                        continue
+                    res_matched, res_ext = self._redo_template(template, row)
+                    confirms[b] = confirms.get(b, 0) + 1
+                    self.stats.host_confirm_pairs += 1
+                    if res_matched:
+                        rowbits[t_idx >> 3] |= 0x80 >> (t_idx & 7)
+                        if res_ext:
+                            uredo_extractions[(b, template.id)] = res_ext
+                pt_value[b] = rowbits
+
+            # --- sparse uncertainty resolution (unique plane) ---
+            t_unc = time.perf_counter()
+            use_native = self._use_native_memo()
+            # row-parallel batched confirm (docs/HOST_WALK.md): resolve the
+            # whole batch's pending (row, matcher) pairs with grouped
+            # GIL-released native passes BEFORE the serial-structured loops
+            # below consume them. walk_threads=0 keeps the reference walk.
+            if use_native and not row_redo.all() and self.walk_threads > 0:
+                pre_m, pre_op = self._precompute_confirms(
+                    nrows, pt_value, pt_unc, pop_value, pop_unc, pm_unc,
+                    set(redo_rows.tolist()),
+                )
+            # (b, t_idx) pairs whose verdict is decided by the extraction
+            # pass below (pseudo-ext templates on the native path)
+            pseudo_pending: list = []
+            if not row_redo.all():
+                skip = set(redo_rows.tolist())
+                if use_native:
+                    from swarm_tpu.native.scanio import plane_bits
+
+                    ub, ut = plane_bits(np.ascontiguousarray(pt_unc), NT)
+                    pairs = zip(ub.tolist(), ut.tolist())
+                else:
+                    pairs = (
+                        (b, byte_i * 8 + k)
+                        for b, byte_i in np.argwhere(pt_unc).tolist()
+                        for k in range(8)
+                        if (int(pt_unc[b, byte_i]) & (0x80 >> k))
+                        and byte_i * 8 + k < NT
+                    )
+                pseudo_t = self._pseudo_t
+                for b, t_idx in pairs:
+                    if b in skip:
+                        continue
+                    byte_i = t_idx >> 3
+                    mask = 0x80 >> (t_idx & 7)
+                    if (
+                        use_native
+                        and t_idx in pseudo_t
+                        and t_idx not in rowdep
+                    ):
+                        # verdict == extraction non-emptiness: decided by
+                        # the batched extraction pass (bit set there on
+                        # extraction); per-pair confirm calls cost ~10x
+                        # the batched native scan at walk rates
+                        pseudo_pending.append((b, t_idx))
+                        pt_value[b, byte_i] &= 0xFF ^ mask
                         continue
                     row = nrows[b]
-                    parts = []
-                    for op_id in t_ops[t_idx]:
-                        if resolve_op(b, op_id, row):
-                            parts.extend(
-                                self._extract_op(self._op_obj[op_id], row)
-                            )
-                    if parts:
-                        uextractions[(b, tids[t_idx])] = parts
+                    if t_idx in rowdep:
+                        # undecided row-dependent template: content-
+                        # identical rows can disagree here — decide per
+                        # member below; clear the broadcast bit
+                        deferred.append((b, t_idx))
+                        pt_value[b, byte_i] &= 0xFF ^ mask
+                        continue
+                    # undecided ⇒ no certain-true op; OR over the
+                    # uncertain ops' exact values decides the template
+                    hit = False
+                    for op_id in self._t_ops_py[t_idx]:
+                        if _bit(pop_unc, b, op_id) and resolve_op(
+                            b, op_id, row
+                        ):
+                            hit = True
+                            break
+                    if hit:
+                        pt_value[b, byte_i] |= mask
+                    else:
+                        pt_value[b, byte_i] &= 0xFF ^ mask
 
-        self.stats.ext_seconds += time.perf_counter() - t_ext
-        self.stats.host_confirm_seconds += time.perf_counter() - t1
-        return (
-            pt_value,
-            uextractions,
-            deferred,
-            set(redo_rows.tolist()),
-            confirms,
-            wf_planes,
-        )
+            # --- extraction pass (unique plane): only extractor templates,
+            # only hit rows (one vectorized gather over all extractor
+            # columns at once — a Python loop over ~600 extractor templates
+            # costs more than the actual extractions). Row-dependent
+            # templates are handled in the member fixup pass ---
+            t_ext = time.perf_counter()
+            self.stats.unc_seconds += t_ext - t_unc
+            uextractions: dict = dict(uredo_extractions)
+            redo_set = set(redo_rows.tolist())
+            if len(self._ext_cols):
+                emask = self._ext_byte_mask
+                masked = pt_value[:, : len(emask)] & emask[None, :]
+                tids = db.template_ids
+                if self._use_native_memo():
+                    # one C pass enumerates the extractor-plane hits AND
+                    # resolves op certainty against the packed planes —
+                    # Python touches only ops that are certainly-true
+                    # (extract) or undecided (resolve_op), in the same
+                    # (b-major, t, op) order the Python loop used
+                    from swarm_tpu.native.scanio import ext_resolve
+
+                    skip_rows = np.zeros(len(nrows), dtype=np.uint8)
+                    for rb in redo_set:
+                        skip_rows[rb] = 1
+                    bs, ts, opsv, sts = ext_resolve(
+                        masked, NT, self._rowdep_mask, skip_rows,
+                        self._t_ops_indptr, self._t_ops_flat,
+                        np.ascontiguousarray(pop_value),
+                        np.ascontiguousarray(pop_unc),
+                    )
+                    t_sub = time.perf_counter()
+                    self.stats.ext_enum_seconds += t_sub - t_ext
+                    # certainty resolution stays in (b-major, t, op) order;
+                    # the regex extractions themselves then run BATCHED —
+                    # one native dispatch per distinct pattern over every
+                    # pending content (per-call overhead dominated the
+                    # fresh-content walk at per-hit rates)
+                    pending: list = []
+                    for b, t_idx, op_id, st in zip(
+                        bs.tolist(), ts.tolist(), opsv.tolist(), sts.tolist()
+                    ):
+                        if st == 2 and not resolve_op(b, op_id, nrows[b]):
+                            continue
+                        pending.append((b, t_idx, op_id))
+                    # deferred pseudo-ext verdicts ride the same batch:
+                    # each uncertain op with >= 1 live pattern joins the
+                    # pending list; its (b, t) verdict bit is set below
+                    # iff the batched extraction produced values
+                    pseudo_set = set()
+                    for b, t_idx in pseudo_pending:
+                        pseudo_set.add((b, t_idx))
+                        for op_id in self._t_ops_py[t_idx]:
+                            if _bit(pop_unc, b, op_id):
+                                pending.append((b, t_idx, op_id))
+                    if pseudo_pending:
+                        self.stats.host_confirm_pairs += len(pseudo_pending)
+                        for b, _t in pseudo_pending:
+                            confirms[b] = confirms.get(b, 0) + 1
+                    # live-pattern hints for per-pattern extraction
+                    # prefilters: the device pm-uncertainty bits already
+                    # say WHICH patterns' literals occurred — the
+                    # extraction pass then skips every other pattern with
+                    # no host scanning at all (certain-false bits are an
+                    # exact no-match proof)
+                    # hints are {ex_local: [p_idx, ...]} with only LIVE
+                    # patterns (flatnonzero is ascending and matcher order
+                    # is (ex_local, p_idx)-ascending, so lists stay in
+                    # pattern order) — consumers never touch the op's full
+                    # pattern population. The pm-plane gather batches per
+                    # op across all its pending rows: one 2D fancy-index
+                    # instead of a per-(row, op) 689-element gather.
+                    hints: dict = {}
+                    by_op: dict = {}
+                    for b, _t_idx, op_id in pending:
+                        if op_id in self._op_ext_pats:
+                            by_op.setdefault(op_id, set()).add(b)
+                    for op_id, bset in by_op.items():
+                        rows_ = sorted(bset)
+                        bits2 = (
+                            pm_unc[np.ix_(rows_, self._op_m_bytes[op_id])]
+                            >> self._op_m_shift[op_id][None, :]
+                        ) & 1
+                        pats = self._op_ext_pats[op_id]
+                        for b in rows_:
+                            hints[(b, op_id)] = {}
+                        # one nonzero over the whole (rows × matchers)
+                        # plane instead of a per-row flatnonzero
+                        ris, ks = np.nonzero(bits2)
+                        for ri, k in zip(ris.tolist(), ks.tolist()):
+                            el, pi = pats[k]
+                            hints[(rows_[ri], op_id)].setdefault(
+                                el, []
+                            ).append(pi)
+                    t_sub2 = time.perf_counter()
+                    self.stats.ext_resolve_seconds += t_sub2 - t_sub
+                    for (b, t_idx), vals in self._extract_pending(
+                        pending, nrows, hints
+                    ).items():
+                        if vals:
+                            uextractions[(b, tids[t_idx])] = vals
+                            if (b, t_idx) in pseudo_set:
+                                # fused verdict: extraction fired
+                                pt_value[b, t_idx >> 3] |= 0x80 >> (t_idx & 7)
+                    self.stats.ext_extract_seconds += (
+                        time.perf_counter() - t_sub2
+                    )
+                else:
+                    hit_b, hit_t = np.nonzero(
+                        np.unpackbits(masked, axis=1, count=NT)
+                    )
+                    t_ops = self._t_ops_py
+                    for b, t_idx in zip(hit_b.tolist(), hit_t.tolist()):
+                        if b in redo_set:
+                            continue  # oracle already extracted above
+                        if t_idx in rowdep:
+                            continue
+                        row = nrows[b]
+                        parts = []
+                        for op_id in t_ops[t_idx]:
+                            if resolve_op(b, op_id, row):
+                                parts.extend(
+                                    self._extract_op(self._op_obj[op_id], row)
+                                )
+                        if parts:
+                            uextractions[(b, tids[t_idx])] = parts
+
+            self.stats.ext_seconds += time.perf_counter() - t_ext
+            self.stats.host_confirm_seconds += time.perf_counter() - t1
+            return (
+                pt_value,
+                uextractions,
+                deferred,
+                set(redo_rows.tolist()),
+                confirms,
+                wf_planes,
+            )
 
     # ------------------------------------------------------------------
     def begin_packed(self, all_rows: Sequence[Response], pre=None):
@@ -2529,9 +2498,7 @@ class MatchEngine:
                     # re-tries the sync path only if the breaker allows)
                     self._note_device_fault(breaker, e)
                 with self._stats_lock:
-                    dt_dev = time.perf_counter() - t0
-                    self.stats.device_seconds += dt_dev
-                    self._note_phase_split(matcher, dt_dev)
+                    self.stats.device_seconds += time.perf_counter() - t0
         return ("native", all_rows, pre, pending)
 
     def finish_packed(self, handle) -> PackedMatches:
@@ -2565,9 +2532,9 @@ class MatchEngine:
             if pre[0] == "native":
                 return self._match_packed_native(all_rows, pre)
         elif self._use_native_memo():
-            return self._match_packed_native(
-                all_rows, self._encode_for_backend(all_rows)
-            )
+            with tracing.span("engine.encode_wait"):
+                enc = self._encode_for_backend(all_rows)
+            return self._match_packed_native(all_rows, enc)
         NT = self.db.num_templates
         nbytes = (NT + 7) >> 3
         # dead rows (no response observed) match nothing by contract —
@@ -2617,7 +2584,10 @@ class MatchEngine:
             )
 
         rows = all_rows
-        enc = pre if pre is not None else self._encode_for_backend(rows)
+        enc = pre
+        if enc is None:
+            with tracing.span("engine.encode_wait"):
+                enc = self._encode_for_backend(rows)
         _tag, batch, matcher, uniq, back, n_src, new_ids, keys, known = enc
         if n_src != len(rows):
             raise ValueError(
@@ -2640,167 +2610,168 @@ class MatchEngine:
             redo_pos = set()
             confirms = {}
             wf_slots = None
-        self.stats.rows += len(rows)
-        self.stats.batches += 1
-        # memo-served rows = everything not mapped to a walked slot
-        # (same row-count semantics as the native path)
-        if len(new_ids) < len(uniq):
-            self.stats.memo_slots += len(rows) - int(
-                np.isin(back, np.asarray(new_ids, dtype=np.int64)).sum()
+        with tracing.span("engine.assemble"):
+            self.stats.rows += len(rows)
+            self.stats.batches += 1
+            # memo-served rows = everything not mapped to a walked slot
+            # (same row-count semantics as the native path)
+            if len(new_ids) < len(uniq):
+                self.stats.memo_slots += len(rows) - int(
+                    np.isin(back, np.asarray(new_ids, dtype=np.int64)).sum()
+                )
+
+            t1 = time.perf_counter()
+            db = self.db
+            # lazy member grouping per unique slot (for per-member fixups
+            # and extraction fan-out): one vectorized argsort instead of a
+            # per-row Python append loop, slices materialized only for the
+            # slots actually touched (extraction hits, row-dependent
+            # deferrals) — at fleet steady state that is a small fraction
+            member_order = np.argsort(back, kind="stable")
+            member_bounds = np.searchsorted(
+                back[member_order], np.arange(len(uniq) + 1)
             )
+            _member_cache: dict = {}
 
-        t1 = time.perf_counter()
-        db = self.db
-        # lazy member grouping per unique slot (for per-member fixups
-        # and extraction fan-out): one vectorized argsort instead of a
-        # per-row Python append loop, slices materialized only for the
-        # slots actually touched (extraction hits, row-dependent
-        # deferrals) — at fleet steady state that is a small fraction
-        member_order = np.argsort(back, kind="stable")
-        member_bounds = np.searchsorted(
-            back[member_order], np.arange(len(uniq) + 1)
-        )
-        _member_cache: dict = {}
-
-        def members_of(ub: int) -> list:
-            m = _member_cache.get(ub)
-            if m is None:
-                m = member_order[
-                    member_bounds[ub] : member_bounds[ub + 1]
-                ].tolist()
-                _member_cache[ub] = m
-            return m
-        rowdep = self._rowdep_t
-        # --- assemble the full unique plane: walked NEW slots + memo-
-        # served known slots; store fully-resolved new content ---
-        U = len(uniq)
-        nbits_row = max(nbytes, 1)
-        ubits = np.zeros((U, nbits_row), dtype=np.uint8)
-        uext_all: dict = {}  # (slot, tid) -> values
-        deferred_slots: list = []  # (slot, t_idx)
-        ext_by_pos: dict = {}
-        for (b, tid), vals in uextractions.items():
-            ext_by_pos.setdefault(int(b), []).append((tid, vals))
-        def_by_pos: dict = {}
-        for b, t_idx in deferred:
-            def_by_pos.setdefault(int(b), []).append(t_idx)
-        shared_wb: list = []
-        for b in range(B):
-            s = new_ids[b]
-            ubits[s] = pt_value[b]
-            for tid, vals in ext_by_pos.get(b, ()):
-                uext_all[(s, tid)] = vals
-            for t_idx in def_by_pos.get(b, ()):
-                deferred_slots.append((s, t_idx))
-            if b not in redo_pos:
-                # deep-freeze what goes in: bits copied out of the
-                # (reused) plane, extraction VALUES tuple-copied —
-                # callers receive mutable lists, and a caller's in-place
-                # edit must never rewrite the cache
-                entry = (
-                    pt_value[b].tobytes(),
-                    tuple(
-                        (tid, tuple(vals))
-                        for tid, vals in ext_by_pos.get(b, ())
-                    ),
-                    tuple(def_by_pos.get(b, ())),
-                )
-                self._cache_put(self._verdict_memo, keys[s], entry)
-                shared_wb.append(
-                    (nrows[b], entry[0], (entry[1], entry[2]))
-                )
-        # shared-tier writeback, dict-memo twin of the native path's
-        # (docs/CACHING.md)
-        if shared_wb and self._result_cache is not None:
-            self._result_cache.writeback_rows(shared_wb)
-        for s, entry in known.items():
-            mb, ment, mdef = entry
-            ubits[s] = np.frombuffer(mb, dtype=np.uint8)
-            for tid, vals in ment:
-                uext_all[(s, tid)] = list(vals)  # thaw per replay
-            for t_idx in mdef:
-                deferred_slots.append((s, t_idx))
-
-        # --- broadcast the unique plane to the source rows ---
-        bits = ubits[back] if len(rows) else ubits[:0]
-        bits = np.ascontiguousarray(bits)
-        wf_rows: Optional[dict] = None
-        if wf_slots is not None:
-            # workflow gate planes broadcast like the verdict plane;
-            # memo-served slots and redo slots stay valid=False (the
-            # runner resolves their conditions on the host)
-            cv, cu, ev, eu = wf_slots
-            uwf = {
-                "cond_v": np.zeros((U, cv.shape[1]), dtype=np.uint8),
-                "cond_u": np.zeros((U, cu.shape[1]), dtype=np.uint8),
-                "emit_v": np.zeros((U, ev.shape[1]), dtype=np.uint8),
-                "emit_u": np.zeros((U, eu.shape[1]), dtype=np.uint8),
-            }
-            uvalid = np.zeros((U,), dtype=bool)
+            def members_of(ub: int) -> list:
+                m = _member_cache.get(ub)
+                if m is None:
+                    m = member_order[
+                        member_bounds[ub] : member_bounds[ub + 1]
+                    ].tolist()
+                    _member_cache[ub] = m
+                return m
+            rowdep = self._rowdep_t
+            # --- assemble the full unique plane: walked NEW slots + memo-
+            # served known slots; store fully-resolved new content ---
+            U = len(uniq)
+            nbits_row = max(nbytes, 1)
+            ubits = np.zeros((U, nbits_row), dtype=np.uint8)
+            uext_all: dict = {}  # (slot, tid) -> values
+            deferred_slots: list = []  # (slot, t_idx)
+            ext_by_pos: dict = {}
+            for (b, tid), vals in uextractions.items():
+                ext_by_pos.setdefault(int(b), []).append((tid, vals))
+            def_by_pos: dict = {}
+            for b, t_idx in deferred:
+                def_by_pos.setdefault(int(b), []).append(t_idx)
+            shared_wb: list = []
             for b in range(B):
                 s = new_ids[b]
-                uwf["cond_v"][s] = cv[b]
-                uwf["cond_u"][s] = cu[b]
-                uwf["emit_v"][s] = ev[b]
-                uwf["emit_u"][s] = eu[b]
-                uvalid[s] = b not in redo_pos
-            wf_rows = {k: v[back] for k, v in uwf.items()}
-            wf_rows["valid"] = uvalid[back]
-        extractions = {}
-        for (ub, tid), vals in uext_all.items():
-            for i in members_of(ub):
-                extractions[(i, tid)] = vals
-        conf_full: dict = {
-            uniq[new_ids[b]]: n for b, n in confirms.items()
-        }
+                ubits[s] = pt_value[b]
+                for tid, vals in ext_by_pos.get(b, ()):
+                    uext_all[(s, tid)] = vals
+                for t_idx in def_by_pos.get(b, ()):
+                    deferred_slots.append((s, t_idx))
+                if b not in redo_pos:
+                    # deep-freeze what goes in: bits copied out of the
+                    # (reused) plane, extraction VALUES tuple-copied —
+                    # callers receive mutable lists, and a caller's in-place
+                    # edit must never rewrite the cache
+                    entry = (
+                        pt_value[b].tobytes(),
+                        tuple(
+                            (tid, tuple(vals))
+                            for tid, vals in ext_by_pos.get(b, ())
+                        ),
+                        tuple(def_by_pos.get(b, ())),
+                    )
+                    self._cache_put(self._verdict_memo, keys[s], entry)
+                    shared_wb.append(
+                        (nrows[b], entry[0], (entry[1], entry[2]))
+                    )
+            # shared-tier writeback, dict-memo twin of the native path's
+            # (docs/CACHING.md)
+            if shared_wb and self._result_cache is not None:
+                self._result_cache.writeback_rows(shared_wb)
+            for s, entry in known.items():
+                mb, ment, mdef = entry
+                ubits[s] = np.frombuffer(mb, dtype=np.uint8)
+                for tid, vals in ment:
+                    uext_all[(s, tid)] = list(vals)  # thaw per replay
+                for t_idx in mdef:
+                    deferred_slots.append((s, t_idx))
 
-        # --- member fixups: row-dependent templates (takeover family's
-        # host gates, duration checks) decided per actual row via the
-        # oracle; also their certain hits' extractions, which may read
-        # host. Rare by construction — these bits only defer when the
-        # content side actually fired ---
-        seen_pairs = set()
-        for ub, t_idx in deferred_slots:
-            if (ub, t_idx) in seen_pairs:
-                continue
-            seen_pairs.add((ub, t_idx))
-            template = db.templates[t_idx]
-            mask = 0x80 >> (t_idx & 7)
-            byte_i = t_idx >> 3
-            for i in members_of(ub):
-                res = cpu_ref.match_template(template, rows[i])
-                conf_full[i] = conf_full.get(i, 0) + 1
-                self.stats.host_confirm_pairs += 1
-                if res.matched:
-                    bits[i, byte_i] |= mask
-                    if res.extractions:
-                        extractions[(i, template.id)] = res.extractions
-                else:
-                    bits[i, byte_i] &= 0xFF ^ mask
-        # certain-set row-dependent templates with extractors: verdict
-        # is content-determined (broadcast is exact) but extraction
-        # values may read the member's host — covers memo-served slots
-        # too (their member set is new every batch), hence ubits
-        for t_idx in self._rowdep_ext_t:
-            byte_i, mask = t_idx >> 3, 0x80 >> (t_idx & 7)
-            template = db.templates[t_idx]
-            for ub in np.flatnonzero(ubits[:, byte_i] & mask):
-                for i in members_of(int(ub)):
+            # --- broadcast the unique plane to the source rows ---
+            bits = ubits[back] if len(rows) else ubits[:0]
+            bits = np.ascontiguousarray(bits)
+            wf_rows: Optional[dict] = None
+            if wf_slots is not None:
+                # workflow gate planes broadcast like the verdict plane;
+                # memo-served slots and redo slots stay valid=False (the
+                # runner resolves their conditions on the host)
+                cv, cu, ev, eu = wf_slots
+                uwf = {
+                    "cond_v": np.zeros((U, cv.shape[1]), dtype=np.uint8),
+                    "cond_u": np.zeros((U, cu.shape[1]), dtype=np.uint8),
+                    "emit_v": np.zeros((U, ev.shape[1]), dtype=np.uint8),
+                    "emit_u": np.zeros((U, eu.shape[1]), dtype=np.uint8),
+                }
+                uvalid = np.zeros((U,), dtype=bool)
+                for b in range(B):
+                    s = new_ids[b]
+                    uwf["cond_v"][s] = cv[b]
+                    uwf["cond_u"][s] = cu[b]
+                    uwf["emit_v"][s] = ev[b]
+                    uwf["emit_u"][s] = eu[b]
+                    uvalid[s] = b not in redo_pos
+                wf_rows = {k: v[back] for k, v in uwf.items()}
+                wf_rows["valid"] = uvalid[back]
+            extractions = {}
+            for (ub, tid), vals in uext_all.items():
+                for i in members_of(ub):
+                    extractions[(i, tid)] = vals
+            conf_full: dict = {
+                uniq[new_ids[b]]: n for b, n in confirms.items()
+            }
+
+            # --- member fixups: row-dependent templates (takeover family's
+            # host gates, duration checks) decided per actual row via the
+            # oracle; also their certain hits' extractions, which may read
+            # host. Rare by construction — these bits only defer when the
+            # content side actually fired ---
+            seen_pairs = set()
+            for ub, t_idx in deferred_slots:
+                if (ub, t_idx) in seen_pairs:
+                    continue
+                seen_pairs.add((ub, t_idx))
+                template = db.templates[t_idx]
+                mask = 0x80 >> (t_idx & 7)
+                byte_i = t_idx >> 3
+                for i in members_of(ub):
                     res = cpu_ref.match_template(template, rows[i])
-                    if res.matched and res.extractions:
-                        extractions[(i, template.id)] = res.extractions
+                    conf_full[i] = conf_full.get(i, 0) + 1
+                    self.stats.host_confirm_pairs += 1
+                    if res.matched:
+                        bits[i, byte_i] |= mask
+                        if res.extractions:
+                            extractions[(i, template.id)] = res.extractions
+                    else:
+                        bits[i, byte_i] &= 0xFF ^ mask
+            # certain-set row-dependent templates with extractors: verdict
+            # is content-determined (broadcast is exact) but extraction
+            # values may read the member's host — covers memo-served slots
+            # too (their member set is new every batch), hence ubits
+            for t_idx in self._rowdep_ext_t:
+                byte_i, mask = t_idx >> 3, 0x80 >> (t_idx & 7)
+                template = db.templates[t_idx]
+                for ub in np.flatnonzero(ubits[:, byte_i] & mask):
+                    for i in members_of(int(ub)):
+                        res = cpu_ref.match_template(template, rows[i])
+                        if res.matched and res.extractions:
+                            extractions[(i, template.id)] = res.extractions
 
-        host_always_matches = self._host_always_tail(rows, extractions)
+            host_always_matches = self._host_always_tail(rows, extractions)
 
-        self.stats.host_confirm_seconds += time.perf_counter() - t1
-        return PackedMatches(
-            bits=bits,
-            template_ids=db.template_ids,
-            extractions=extractions,
-            host_always_matches=host_always_matches,
-            confirms_per_row=conf_full,
-            wf=wf_rows,
-        )
+            self.stats.host_confirm_seconds += time.perf_counter() - t1
+            return PackedMatches(
+                bits=bits,
+                template_ids=db.template_ids,
+                extractions=extractions,
+                host_always_matches=host_always_matches,
+                confirms_per_row=conf_full,
+                wf=wf_rows,
+            )
 
     # ------------------------------------------------------------------
     def _match_packed_native(self, rows, enc, pending=None) -> PackedMatches:
@@ -2821,171 +2792,173 @@ class MatchEngine:
         db = self.db
         self.stats.rows += len(rows)
         self.stats.batches += 1
-        extractions: dict = {}
-        conf_full: dict = {}
-        deferred_rows: list = []  # (row_i, t_idx) — decide per row
-        wf_rows: Optional[dict] = None
         if batch is not None:
             nrows = [rows[i] for i in miss_uniq]
             B = len(nrows)
             pt_value, uext, deferred, redo_pos, confirms, wf_slots = (
                 self._walk_plane(nrows, batch, matcher, pending=pending)
             )
-            t1 = time.perf_counter()
-            self.stats.memo_slots += int((state == -1).sum())
-            # broadcast walked bits to their member rows
-            miss_rows = np.flatnonzero(state >= 0)
-            bits[miss_rows] = pt_value[state[miss_rows]]
-            if wf_slots is not None:
-                # workflow gate planes for walked rows; memo-served
-                # rows stay valid=False (host-resolved by the runner)
-                cv, cu, ev, eu = wf_slots
-                R = len(rows)
-                sl = state[miss_rows]
-                wf_rows = {
-                    "cond_v": np.zeros((R, cv.shape[1]), dtype=np.uint8),
-                    "cond_u": np.zeros((R, cu.shape[1]), dtype=np.uint8),
-                    "emit_v": np.zeros((R, ev.shape[1]), dtype=np.uint8),
-                    "emit_u": np.zeros((R, eu.shape[1]), dtype=np.uint8),
-                }
-                wf_rows["cond_v"][miss_rows] = cv[sl]
-                wf_rows["cond_u"][miss_rows] = cu[sl]
-                wf_rows["emit_v"][miss_rows] = ev[sl]
-                wf_rows["emit_u"][miss_rows] = eu[sl]
-                slot_ok = np.ones((B,), dtype=bool)
+        with tracing.span("engine.assemble"):
+            extractions: dict = {}
+            conf_full: dict = {}
+            deferred_rows: list = []  # (row_i, t_idx) — decide per row
+            wf_rows: Optional[dict] = None
+            if batch is not None:
+                t1 = time.perf_counter()
+                self.stats.memo_slots += int((state == -1).sum())
+                # broadcast walked bits to their member rows
+                miss_rows = np.flatnonzero(state >= 0)
+                bits[miss_rows] = pt_value[state[miss_rows]]
+                if wf_slots is not None:
+                    # workflow gate planes for walked rows; memo-served
+                    # rows stay valid=False (host-resolved by the runner)
+                    cv, cu, ev, eu = wf_slots
+                    R = len(rows)
+                    sl = state[miss_rows]
+                    wf_rows = {
+                        "cond_v": np.zeros((R, cv.shape[1]), dtype=np.uint8),
+                        "cond_u": np.zeros((R, cu.shape[1]), dtype=np.uint8),
+                        "emit_v": np.zeros((R, ev.shape[1]), dtype=np.uint8),
+                        "emit_u": np.zeros((R, eu.shape[1]), dtype=np.uint8),
+                    }
+                    wf_rows["cond_v"][miss_rows] = cv[sl]
+                    wf_rows["cond_u"][miss_rows] = cu[sl]
+                    wf_rows["emit_v"][miss_rows] = ev[sl]
+                    wf_rows["emit_u"][miss_rows] = eu[sl]
+                    slot_ok = np.ones((B,), dtype=bool)
+                    for pos in redo_pos:
+                        slot_ok[pos] = False
+                    valid = np.zeros((R,), dtype=bool)
+                    valid[miss_rows] = slot_ok[sl]
+                    wf_rows["valid"] = valid
+                ext_by_pos: dict = {}
+                for (b, tid), vals in uext.items():
+                    ext_by_pos.setdefault(int(b), []).append((tid, vals))
+                def_by_pos: dict = {}
+                for b, t_idx in deferred:
+                    def_by_pos.setdefault(int(b), []).append(t_idx)
+                # memo inserts for fully-resolved content (deep-frozen
+                # extras — callers receive thawed list copies, so a
+                # caller's in-place edit can never rewrite the cache;
+                # truncated/overflow positions are never stored). One
+                # native call inserts the whole walked plane.
+                t_ins = time.perf_counter()
+                skip = np.zeros(B, dtype=np.uint8)
                 for pos in redo_pos:
-                    slot_ok[pos] = False
-                valid = np.zeros((R,), dtype=bool)
-                valid[miss_rows] = slot_ok[sl]
-                wf_rows["valid"] = valid
-            ext_by_pos: dict = {}
-            for (b, tid), vals in uext.items():
-                ext_by_pos.setdefault(int(b), []).append((tid, vals))
-            def_by_pos: dict = {}
-            for b, t_idx in deferred:
-                def_by_pos.setdefault(int(b), []).append(t_idx)
-            # memo inserts for fully-resolved content (deep-frozen
-            # extras — callers receive thawed list copies, so a
-            # caller's in-place edit can never rewrite the cache;
-            # truncated/overflow positions are never stored). One
-            # native call inserts the whole walked plane.
-            t_ins = time.perf_counter()
-            skip = np.zeros(B, dtype=np.uint8)
-            for pos in redo_pos:
-                skip[pos] = 1
-            extras_list: list = [None] * B
-            for pos in ext_by_pos.keys() | def_by_pos.keys():
-                ment = tuple(
-                    (tid, tuple(vals))
-                    for tid, vals in ext_by_pos.get(pos, ())
-                )
-                mdef = tuple(def_by_pos.get(pos, ()))
-                if ment or mdef:
-                    extras_list[pos] = (ment, mdef)
-            self._vmemo.insert_batch(nrows, pt_value[:B], skip, extras_list)
-            # shared-tier writeback (docs/CACHING.md): the same fully-
-            # resolved planes the L1 just absorbed batch-write to the
-            # fleet tier — truncated/overflow positions stay local-only
-            # exactly like the L1 (never memoized anywhere). Runs after
-            # finish_packed's walk, off the dispatch path; a fenced or
-            # degraded put drops silently (the tier is an accelerator,
-            # never a dependency).
-            if (
-                self._result_cache is not None
-                and self._result_cache.writeback
-            ):
-                self._result_cache.writeback_rows(
-                    [
-                        (nrows[b], pt_value[b].tobytes(), extras_list[b])
-                        for b in range(B)
-                        if not skip[b]
-                    ]
-                )
-            ins_dt = time.perf_counter() - t_ins
-            self.stats.insert_seconds += ins_dt
-            # member fan-out over miss rows. Fresh-content batches
-            # (every row a unique miss) skip the argsort grouping —
-            # slot s's only member is miss_uniq[s].
-            if len(miss_uniq) == len(rows):
-                def members_of(pos: int) -> tuple:
-                    return (miss_uniq[pos],)
-            else:
-                order = np.argsort(state, kind="stable")
-                sorted_state = state[order]
+                    skip[pos] = 1
+                extras_list: list = [None] * B
+                for pos in ext_by_pos.keys() | def_by_pos.keys():
+                    ment = tuple(
+                        (tid, tuple(vals))
+                        for tid, vals in ext_by_pos.get(pos, ())
+                    )
+                    mdef = tuple(def_by_pos.get(pos, ()))
+                    if ment or mdef:
+                        extras_list[pos] = (ment, mdef)
+                self._vmemo.insert_batch(nrows, pt_value[:B], skip, extras_list)
+                # shared-tier writeback (docs/CACHING.md): the same fully-
+                # resolved planes the L1 just absorbed batch-write to the
+                # fleet tier — truncated/overflow positions stay local-only
+                # exactly like the L1 (never memoized anywhere). Runs after
+                # finish_packed's walk, off the dispatch path; a fenced or
+                # degraded put drops silently (the tier is an accelerator,
+                # never a dependency).
+                if (
+                    self._result_cache is not None
+                    and self._result_cache.writeback
+                ):
+                    self._result_cache.writeback_rows(
+                        [
+                            (nrows[b], pt_value[b].tobytes(), extras_list[b])
+                            for b in range(B)
+                            if not skip[b]
+                        ]
+                    )
+                ins_dt = time.perf_counter() - t_ins
+                self.stats.insert_seconds += ins_dt
+                # member fan-out over miss rows. Fresh-content batches
+                # (every row a unique miss) skip the argsort grouping —
+                # slot s's only member is miss_uniq[s].
+                if len(miss_uniq) == len(rows):
+                    def members_of(pos: int) -> tuple:
+                        return (miss_uniq[pos],)
+                else:
+                    order = np.argsort(state, kind="stable")
+                    sorted_state = state[order]
 
-                def members_of(pos: int) -> list:
-                    lo = np.searchsorted(sorted_state, pos)
-                    hi = np.searchsorted(sorted_state, pos + 1)
-                    return order[lo:hi].tolist()
+                    def members_of(pos: int) -> list:
+                        lo = np.searchsorted(sorted_state, pos)
+                        hi = np.searchsorted(sorted_state, pos + 1)
+                        return order[lo:hi].tolist()
 
-            for (pos, tid), vals in uext.items():
-                for i in members_of(int(pos)):
-                    extractions[(i, tid)] = vals
-            for pos, tids in def_by_pos.items():
-                for i in members_of(pos):
-                    for t_idx in tids:
-                        deferred_rows.append((i, t_idx))
-            conf_full = {
-                miss_uniq[pos]: n for pos, n in confirms.items()
-            }
-        else:
-            ins_dt = 0.0
-            t1 = time.perf_counter()
-            self.stats.memo_slots += int((state == -1).sum())
-        # extras served by the memo arrive ALREADY applied by the C
-        # lookup: a (row, tid) -> thawed-list dict plus the
-        # row-dependent deferral pairs. At steady state (no walked
-        # extractions yet) the C-built dict is adopted wholesale.
-        extr_known, deferred_known = served
-        if extractions:
-            extractions.update(extr_known)
-        else:
-            extractions = extr_known
-        deferred_rows.extend(deferred_known)
-        # certain-set row-dependent templates with extractors: at this
-        # point the bits plane is content-certain (deferred bits are
-        # cleared), so a set bit broadcasts exactly — but extraction
-        # values may read the row's host → oracle per hit row. Runs
-        # BEFORE the deferred fixups so fixup-set bits don't re-run.
-        for t_idx in self._rowdep_ext_t:
-            byte_i, mask = t_idx >> 3, 0x80 >> (t_idx & 7)
-            template = db.templates[t_idx]
-            for i in np.flatnonzero(bits[:, byte_i] & mask):
-                res = cpu_ref.match_template(template, rows[int(i)])
-                if res.matched and res.extractions:
-                    extractions[(int(i), template.id)] = res.extractions
-        # row-dependent deferrals (takeover family's host gates,
-        # duration checks): decided per actual row via the oracle
-        for i, t_idx in deferred_rows:
-            template = db.templates[t_idx]
-            mask = 0x80 >> (t_idx & 7)
-            byte_i = t_idx >> 3
-            res = cpu_ref.match_template(template, rows[i])
-            conf_full[i] = conf_full.get(i, 0) + 1
-            self.stats.host_confirm_pairs += 1
-            if res.matched:
-                bits[i, byte_i] |= mask
-                if res.extractions:
-                    extractions[(i, template.id)] = res.extractions
+                for (pos, tid), vals in uext.items():
+                    for i in members_of(int(pos)):
+                        extractions[(i, tid)] = vals
+                for pos, tids in def_by_pos.items():
+                    for i in members_of(pos):
+                        for t_idx in tids:
+                            deferred_rows.append((i, t_idx))
+                conf_full = {
+                    miss_uniq[pos]: n for pos, n in confirms.items()
+                }
             else:
-                bits[i, byte_i] &= 0xFF ^ mask
-        host_always_matches = self._host_always_tail(
-            rows, extractions, dead_state=state
-        )
-        now = time.perf_counter()
-        # the insert window sits inside t1..now but is attributed to
-        # insert_seconds — exclude it so the sub-phases sum to the
-        # host_confirm total instead of double-counting
-        self.stats.fixup_seconds += now - t1 - ins_dt
-        self.stats.host_confirm_seconds += now - t1
-        return PackedMatches(
-            bits=bits,
-            template_ids=db.template_ids,
-            extractions=extractions,
-            host_always_matches=host_always_matches,
-            confirms_per_row=conf_full,
-            wf=wf_rows,
-        )
+                ins_dt = 0.0
+                t1 = time.perf_counter()
+                self.stats.memo_slots += int((state == -1).sum())
+            # extras served by the memo arrive ALREADY applied by the C
+            # lookup: a (row, tid) -> thawed-list dict plus the
+            # row-dependent deferral pairs. At steady state (no walked
+            # extractions yet) the C-built dict is adopted wholesale.
+            extr_known, deferred_known = served
+            if extractions:
+                extractions.update(extr_known)
+            else:
+                extractions = extr_known
+            deferred_rows.extend(deferred_known)
+            # certain-set row-dependent templates with extractors: at this
+            # point the bits plane is content-certain (deferred bits are
+            # cleared), so a set bit broadcasts exactly — but extraction
+            # values may read the row's host → oracle per hit row. Runs
+            # BEFORE the deferred fixups so fixup-set bits don't re-run.
+            for t_idx in self._rowdep_ext_t:
+                byte_i, mask = t_idx >> 3, 0x80 >> (t_idx & 7)
+                template = db.templates[t_idx]
+                for i in np.flatnonzero(bits[:, byte_i] & mask):
+                    res = cpu_ref.match_template(template, rows[int(i)])
+                    if res.matched and res.extractions:
+                        extractions[(int(i), template.id)] = res.extractions
+            # row-dependent deferrals (takeover family's host gates,
+            # duration checks): decided per actual row via the oracle
+            for i, t_idx in deferred_rows:
+                template = db.templates[t_idx]
+                mask = 0x80 >> (t_idx & 7)
+                byte_i = t_idx >> 3
+                res = cpu_ref.match_template(template, rows[i])
+                conf_full[i] = conf_full.get(i, 0) + 1
+                self.stats.host_confirm_pairs += 1
+                if res.matched:
+                    bits[i, byte_i] |= mask
+                    if res.extractions:
+                        extractions[(i, template.id)] = res.extractions
+                else:
+                    bits[i, byte_i] &= 0xFF ^ mask
+            host_always_matches = self._host_always_tail(
+                rows, extractions, dead_state=state
+            )
+            now = time.perf_counter()
+            # the insert window sits inside t1..now but is attributed to
+            # insert_seconds — exclude it so the sub-phases sum to the
+            # host_confirm total instead of double-counting
+            self.stats.fixup_seconds += now - t1 - ins_dt
+            self.stats.host_confirm_seconds += now - t1
+            return PackedMatches(
+                bits=bits,
+                template_ids=db.template_ids,
+                extractions=extractions,
+                host_always_matches=host_always_matches,
+                confirms_per_row=conf_full,
+                wf=wf_rows,
+            )
 
 
     def _host_always_tail(

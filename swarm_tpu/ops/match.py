@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import functools
 import threading
-import time
 import warnings
 from typing import Optional
 
@@ -48,6 +47,12 @@ warnings.filterwarnings(
 from swarm_tpu.fingerprints import compile as fpc
 from swarm_tpu.ops import hashing
 from swarm_tpu.ops.encoding import STREAMS
+from swarm_tpu.telemetry import tracing
+
+# every span (telemetry/tracing.py) of a process that matches on the
+# device also reaches a recording JAX profiler session, on the device
+# trace's clock
+tracing.install_profiler_hook(jax.profiler.TraceAnnotation)
 
 
 #: max live compiled step functions on the SHARDED matcher (its pjit
@@ -544,6 +549,7 @@ class DeviceDB:
                 # jit-captures: db, meta, k, full (host metadata +
                 # scalars — trace-static by construction; the corpus
                 # rides the `arrays` ARGUMENT, never the closure)
+                # name pinned: kernel.match_ms reads jit_kernel (test_program_spans)
                 def kernel(arrays, streams, lengths, status):
                     out = _match_impl_args(
                         db, meta, k, arrays, streams, lengths, status,
@@ -579,6 +585,7 @@ class DeviceDB:
 
                 # jit-captures: meta, budget (layout metadata + a
                 # python int; both trace-static)
+                # name pinned: kernel.match_ms reads jit_kernel_a (test_program_spans)
                 def kernel_a(arrays, streams, lengths):
                     streams = ensure_all_stream(streams, lengths)
                     ctx = _StreamCtx(streams, lengths)
@@ -613,6 +620,7 @@ class DeviceDB:
 
             # jit-captures: db, meta, k, full (same contract as the
             # fused kernel: metadata and scalars only)
+            # name pinned: kernel.match_ms reads jit_kernel_b (test_program_spans)
             def kernel_b(kc, arrays, streams, lengths, status, cnt,
                          overflow):
                 streams = ensure_all_stream(streams, lengths)
@@ -777,6 +785,7 @@ class DeviceDB:
                         fn.clear_cache()
         return out
 
+    @tracing.spanned("device.launch")
     def dispatch(self, streams: dict, lengths: dict, status, full: bool = True):
         """Async half of :meth:`match`: stage the batch, launch the
         kernel(s), and return the (device-resident, still-computing)
@@ -791,7 +800,6 @@ class DeviceDB:
         width; phase B itself is launched asynchronously at that
         width."""
         from swarm_tpu.resilience.faults import fault_point
-        from swarm_tpu.telemetry import tracing
 
         # always-on flight-ring record BEFORE the fault point: when a
         # seeded device.dispatch fault fires, the resulting flight dump
@@ -829,23 +837,18 @@ class DeviceDB:
 
         # requires-lock: _counter_lock (invoked via _spied_launch)
         def launch():
-            t_a = time.perf_counter()
             cnt, overflow, nmax = fa(arrays, s_j, l_j)
             # the ONE host sync between phases: a scalar read that
             # sizes phase B to live work instead of worst-case budget
-            # host-sync-ok: the blessed 4-byte phase-A survivor scalar
-            n_live = int(nmax)
-            # the scalar read blocks on phase A, so the wall up to here
-            # IS phase A — MatchEngine pops it into EngineStats
-            # phase_a/phase_b attribution (one consumer per dispatch)
-            phase_a_s = time.perf_counter() - t_a
+            with tracing.span("device.phase_a_wait"):
+                # host-sync-ok: the blessed 4-byte phase-A survivor scalar
+                n_live = int(nmax)
             kc = fpc.survivor_bucket(n_live, budget)
             out = fb(kc, arrays, s_j, l_j, st_j, cnt, overflow)
             self.last_compact = {
                 "survivor_max": n_live,
                 "verify_k": kc,
                 "budget": budget,
-                "phase_a_s": phase_a_s,
             }
             return out
 
@@ -863,7 +866,9 @@ class DeviceDB:
     def collect(self, out):
         """Blocking half of the full-mode split: one host read of the
         fused plane array, sliced into the engine's six outputs."""
-        return split_fused(self.db, np.asarray(out))
+        with tracing.span("device.result_wait"):
+            out = np.asarray(out)
+        return split_fused(self.db, out)
 
     # ------------------------------------------------------------------
     def profile_phases(self, streams: dict, lengths: dict, status) -> dict:

@@ -24,6 +24,7 @@ from swarm_tpu.fingerprints.nmap_probes import (
     probe_for_port,
     substitute_version,
 )
+from swarm_tpu.telemetry import tracing
 
 
 @dataclasses.dataclass
@@ -152,6 +153,7 @@ class ServiceClassifier:
             order.append("NULL")
         return order
 
+    @tracing.spanned("classifier.classify")
     def classify(
         self,
         rows: Sequence[Response],
@@ -159,78 +161,81 @@ class ServiceClassifier:
     ) -> list[ServiceInfo]:
         results = self.engine.match(rows)
         out: list[ServiceInfo] = []
-        for i, (row, hits) in enumerate(zip(rows, results)):
-            info = ServiceInfo(host=row.host, port=row.port, open=row.alive)
-            banner = row.part("body")
-            if not row.alive or not banner:
-                out.append(info)
-                continue
-            # fleet banners repeat heavily (every OpenSSH 8.9 host says
-            # the same bytes): the whole verify/veto walk below is a
-            # pure function of (banner, sent probe), so memo its
-            # service fields across rows and batches
-            sent = sent_probes[i] if sent_probes else None
-            mkey = (banner, sent)
-            memo = self._classify_memo.get(mkey)
-            if memo is not None:
-                (
-                    info.service, info.product, info.version,
-                    info.info, cpe, info.soft,
-                ) = memo
-                info.cpe = list(cpe)  # callers may mutate their copy
-                out.append(info)
-                continue
-            cand = {
-                int(tid.rsplit("/", 1)[1])
-                for tid in hits.template_ids
-                if tid.startswith("svc/")
-            }
-            probe_order = self._probe_order(sent)
-            if probe_order is None:
-                ordered = sorted(cand)
-            else:
-                ordered = [
-                    idx
-                    for pname in probe_order
-                    for idx in self._by_probe.get(pname, [])
-                    if idx in cand
-                ]
-            soft_hit: Optional[ServiceMatch] = None
-            hard_done = False
-            for idx in ordered:
-                _probe_name, match = self._matches[idx]
-                pattern = self._compiled[idx]
-                mo = pattern.search(banner) if pattern else None
-                if not mo:
-                    continue  # device prefilter is a superset; host veto
-                if match.soft:
-                    soft_hit = soft_hit or match
+        # the veto walk: each row's service fields from the device
+        # prefilter's candidates (memo, nmap order, regex, versions)
+        with tracing.span("classifier.veto_walk"):
+            for i, (row, hits) in enumerate(zip(rows, results)):
+                info = ServiceInfo(host=row.host, port=row.port, open=row.alive)
+                banner = row.part("body")
+                if not row.alive or not banner:
+                    out.append(info)
                     continue
-                # after a softmatch names a service, only hard matches for
-                # that same service may win (nmap softmatch semantics)
-                if soft_hit is not None and match.service != soft_hit.service:
+                # fleet banners repeat heavily (every OpenSSH 8.9 host says
+                # the same bytes): the whole verify/veto walk below is a
+                # pure function of (banner, sent probe), so memo its
+                # service fields across rows and batches
+                sent = sent_probes[i] if sent_probes else None
+                mkey = (banner, sent)
+                memo = self._classify_memo.get(mkey)
+                if memo is not None:
+                    (
+                        info.service, info.product, info.version,
+                        info.info, cpe, info.soft,
+                    ) = memo
+                    info.cpe = list(cpe)  # callers may mutate their copy
+                    out.append(info)
                     continue
-                info.service = match.service
-                info.product = substitute_version(match.product, mo)
-                info.version = substitute_version(match.version, mo)
-                info.info = substitute_version(match.info, mo)
-                info.cpe = [substitute_version(c, mo) for c in match.cpe]
-                hard_done = True
-                break
-            if not hard_done and soft_hit:
-                info.service = soft_hit.service
-                info.soft = True
-            # tuple-copy cpe: the caller owns (and may mutate) its list.
-            # Bounding shares the engine's memo policy (_cache_put).
-            self.engine._cache_put(
-                self._classify_memo,
-                mkey,
-                (
-                    info.service, info.product, info.version,
-                    info.info, tuple(info.cpe), info.soft,
-                ),
-            )
-            out.append(info)
+                cand = {
+                    int(tid.rsplit("/", 1)[1])
+                    for tid in hits.template_ids
+                    if tid.startswith("svc/")
+                }
+                probe_order = self._probe_order(sent)
+                if probe_order is None:
+                    ordered = sorted(cand)
+                else:
+                    ordered = [
+                        idx
+                        for pname in probe_order
+                        for idx in self._by_probe.get(pname, [])
+                        if idx in cand
+                    ]
+                soft_hit: Optional[ServiceMatch] = None
+                hard_done = False
+                for idx in ordered:
+                    _probe_name, match = self._matches[idx]
+                    pattern = self._compiled[idx]
+                    mo = pattern.search(banner) if pattern else None
+                    if not mo:
+                        continue  # device prefilter is a superset; host veto
+                    if match.soft:
+                        soft_hit = soft_hit or match
+                        continue
+                    # after a softmatch names a service, only hard matches for
+                    # that same service may win (nmap softmatch semantics)
+                    if soft_hit is not None and match.service != soft_hit.service:
+                        continue
+                    info.service = match.service
+                    info.product = substitute_version(match.product, mo)
+                    info.version = substitute_version(match.version, mo)
+                    info.info = substitute_version(match.info, mo)
+                    info.cpe = [substitute_version(c, mo) for c in match.cpe]
+                    hard_done = True
+                    break
+                if not hard_done and soft_hit:
+                    info.service = soft_hit.service
+                    info.soft = True
+                # tuple-copy cpe: the caller owns (and may mutate) its list.
+                # Bounding shares the engine's memo policy (_cache_put).
+                self.engine._cache_put(
+                    self._classify_memo,
+                    mkey,
+                    (
+                        info.service, info.product, info.version,
+                        info.info, tuple(info.cpe), info.soft,
+                    ),
+                )
+                out.append(info)
         return out
 
     # ------------------------------------------------------------------

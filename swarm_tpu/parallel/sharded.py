@@ -90,6 +90,7 @@ from swarm_tpu.ops.match import (
     verify_candidates,
 )
 from swarm_tpu.ops.md5 import md5_words
+from swarm_tpu.telemetry import tracing
 
 
 def shard_tables_np(db: fpc.CompiledDB, ranks: int) -> list[dict]:
@@ -728,6 +729,7 @@ class ShardedMatcher:
         # jit-captures: self, db, meta, candidate_k, full (host metadata
         # + scalars — trace-static; the corpus rides the tab/rep
         # ARGUMENTS, never the closure)
+        # name pinned: kernel.match_ms reads jit_step (test_program_spans)
         def step(tab, rep, streams, lengths, status):
             streams_ext, offsets, back, fwd = self._exchange_halos(streams)
             arrays = {
@@ -830,6 +832,7 @@ class ShardedMatcher:
 
         # jit-captures: self, meta, budget, carry (layout metadata +
         # python scalars; all trace-static)
+        # name pinned: kernel.match_ms reads jit_step_a (test_program_spans)
         def step_a(tab, streams, lengths):
             streams_ext, offsets, back, fwd = self._exchange_halos(streams)
             ctx = _StreamCtx(streams_ext, lengths, offsets)
@@ -895,6 +898,7 @@ class ShardedMatcher:
 
         # jit-captures: self, db, meta, budget, kc (metadata and
         # scalars only — kc is the ladder rung this executable serves)
+        # name pinned: kernel.match_ms reads jit_step_bp (test_program_spans)
         def step_bp(tab, rep, streams, lengths, cnt_r):
             ctx, local, back, fwd = self._ext_ctx(streams, lengths)
             tabr = {k: v[0] for k, v in tab.items()}
@@ -966,6 +970,7 @@ class ShardedMatcher:
 
         # jit-captures: self, full_flag, carry, h (python scalars —
         # trace-static; corpus rides the rep ARGUMENT)
+        # name pinned: kernel.match_ms reads jit_finish (test_program_spans)
         def finish(rep, streams, lengths, status, vb_r, ub_r, ovf_r):
             local = streams
             if carry:
@@ -996,6 +1001,7 @@ class ShardedMatcher:
         else:
 
             # jit-captures: finish (the closure above)
+            # name pinned: kernel.match_ms reads jit_step_r (test_program_spans)
             def step_r(rep, lengths, status, vb_r, ub_r, ovf_r):
                 return finish(rep, {}, lengths, status, vb_r, ub_r, ovf_r)
 
@@ -1195,6 +1201,7 @@ class ShardedMatcher:
                 m.HALO_SAVED.inc(saved_rounds * round_bytes)
 
     # ------------------------------------------------------------------
+    @tracing.spanned("device.launch")
     def dispatch(self, streams: dict, lengths: dict, status, full: bool = True):
         """Async half of :meth:`match`: stage the batch, launch the
         sharded kernels, and return WITHOUT a full host transfer — the
@@ -1263,10 +1270,11 @@ class ShardedMatcher:
         # jit-hygiene contract (tools/swarmlint). Single-controller
         # meshes read the per-rank lanes (R × 4 bytes, no collective);
         # multi-controller meshes read their pmax'd replicated scalar.
-        if self.multiprocess:
-            n_live = int(nmax)  # host-sync-ok: the blessed sharded 4-byte phase-A survivor scalar
-        else:
-            n_live = int(np.asarray(nmax).max())  # host-sync-ok: the blessed sharded phase-A survivor lanes (R × 4 bytes)
+        with tracing.span("device.phase_a_wait"):
+            if self.multiprocess:
+                n_live = int(nmax)  # host-sync-ok: the blessed sharded 4-byte phase-A survivor scalar
+            else:
+                n_live = int(np.asarray(nmax).max())  # host-sync-ok: the blessed sharded phase-A survivor lanes (R × 4 bytes)
         budget = global_candidate_budget(
             self.candidate_k, len(self.meta.table_stream)
         )
@@ -1320,7 +1328,9 @@ class ShardedMatcher:
             out = multihost_utils.global_array_to_host_local_array(
                 out, self.mesh, P()
             )
-        res = split_fused(self.db, np.asarray(out))
+        with tracing.span("device.result_wait"):
+            out = np.asarray(out)
+        res = split_fused(self.db, out)
         if deferred:
             # launch-if-needed + device wait + the host read: how long
             # collect actually stalled on the reduction (≈0 when a

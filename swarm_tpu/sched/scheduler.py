@@ -591,6 +591,12 @@ class BatchScheduler:
                     chunk_left[cid] -= k2 - k
                     k = k2
 
+        def walk_batch(bound, pb: PlannedBatch, handle) -> None:
+            # on the walk worker: the submitting thread's trace binding,
+            # so the walk's spans land in the attempt's waterfall
+            with bound:
+                finish_batch(pb, handle)
+
         def drain_walks(limit: int) -> None:
             # .result() re-raises a walk failure on the submit thread —
             # a failing walk must fail the run, not vanish in a worker
@@ -608,8 +614,9 @@ class BatchScheduler:
                 # batch N+1 — the device never waits for the walk. One
                 # walk in flight: the worker serializes walks, and the
                 # bound keeps the encode-plane budget exact.
-                walking.append((pb, walk_exec.submit(finish_batch, pb,
-                                                     handle)))
+                walking.append((pb, walk_exec.submit(
+                    walk_batch, tracing.carry(), pb, handle
+                )))
                 stats.offloaded_walks += 1
                 _WALK_OFFLOADED.inc()
                 drain_walks(1)

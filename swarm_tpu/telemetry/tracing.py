@@ -13,12 +13,15 @@ assembled by the server into a per-scan WATERFALL blob under
 
 Three design rules, in priority order:
 
-1. **Near-zero cost when disabled** (the default). ``span()`` is two
-   global loads and one thread-local getattr before returning the
-   shared no-op span; the completed-job wire payload is byte-identical
-   to the untraced build. Enable with ``SWARM_TRACE=1`` (env) or
-   ``tracing.set_enabled(True)`` (runtime override, used by tests and
-   the bench so they never mutate os.environ).
+1. **Near-zero cost when disabled** (the default). ``span()`` is a few
+   global loads before returning the shared no-op span — or, once the
+   ops layer has installed the JAX profiler hook
+   (:func:`install_profiler_hook`), a bare ``TraceAnnotation`` that
+   records only while a profiler session is recording; the
+   completed-job wire payload is byte-identical to the untraced build.
+   Enable with ``SWARM_TRACE=1`` (env) or ``tracing.set_enabled(True)``
+   (runtime override, used by tests and the bench so they never mutate
+   os.environ).
 2. **Spans never block the data path.** Every collection structure is
    bounded (per-attempt list, per-scan assembly state, scan LRU,
    blob retention) and overflow increments
@@ -39,6 +42,7 @@ degraded worker get the last N records of context for free.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
@@ -62,6 +66,10 @@ _TRUTHY = ("1", "true", "yes", "on")
 
 _override: Optional[bool] = None  # set_enabled() runtime override
 _env_cached: Optional[bool] = None  # lazy one-time env read
+#: the JAX profiler's annotation type, installed by the ops layer (which
+#: imports jax anyway); None in processes that never import it (server,
+#: CLI), so this module never imports jax itself
+_annotation: Optional[Callable[[str], Any]] = None
 
 
 def _read_env() -> bool:
@@ -87,6 +95,16 @@ def set_enabled(on: Optional[bool]) -> None:
     global _override, _env_cached
     _override = on
     _env_cached = None
+
+
+def install_profiler_hook(annotation: Callable[[str], Any]) -> None:
+    """Send every span to ``annotation(name)`` as well — a context
+    manager, ``jax.profiler.TraceAnnotation`` — so that a recording
+    profiler session (the benchmark's ``--trace 1``, ``SWARM_PROFILE_DIR``)
+    carries each span under its name, on the device trace's clock,
+    whether operator tracing is on or not."""
+    global _annotation
+    _annotation = annotation
 
 
 def new_span_id() -> str:
@@ -159,24 +177,6 @@ class TraceContext:
             duration_s=span.get("duration_s"),
         )
 
-    def add_synth(
-        self,
-        name: str,
-        start: float,
-        duration_s: float,
-        parent_id: Optional[str] = None,
-        **attrs: Any,
-    ) -> str:
-        """Record a span synthesized from pre-measured timings (e.g.
-        EngineStats phase deltas); returns its span_id so callers can
-        hang children off it."""
-        span = make_span(
-            name, self.trace_id, start, duration_s,
-            parent_id=parent_id or self.root_id, **attrs,
-        )
-        self.add(span)
-        return span["span_id"]
-
     def span_count(self) -> int:
         with self._lock:
             return len(self._spans)
@@ -211,10 +211,12 @@ class activate:
     """Bind ``ctx`` as the calling thread's ambient trace context for
     the ``with`` body (restores the previous binding on exit). A None
     ctx is a no-op binding — callers never need their own branch for
-    the disabled case."""
+    the disabled case. ``parent`` is the span id new spans nest under
+    (None: the context's root)."""
 
-    def __init__(self, ctx: Optional[TraceContext]):
+    def __init__(self, ctx: Optional[TraceContext], parent: Optional[str] = None):
         self._ctx = ctx
+        self._parent = parent
         self._prev_ctx: Optional[TraceContext] = None
         self._prev_span: Optional[str] = None
 
@@ -222,7 +224,7 @@ class activate:
         self._prev_ctx = getattr(_tls, "ctx", None)
         self._prev_span = getattr(_tls, "span", None)
         _tls.ctx = self._ctx
-        _tls.span = None
+        _tls.span = self._parent
         return self._ctx
 
     def __exit__(self, *exc: Any) -> bool:
@@ -243,9 +245,17 @@ def current_context() -> Optional[TraceContext]:
     return getattr(_tls, "ctx", None)
 
 
+def carry() -> activate:
+    """The calling thread's binding — its context and open span — to
+    enter on a worker thread, so the spans opened there nest where the
+    work was handed off."""
+    return activate(getattr(_tls, "ctx", None), getattr(_tls, "span", None))
+
+
 class _NullSpan:
     """Shared no-op span: the entire cost of ``with span(...)`` when
-    tracing is disabled or no context is bound."""
+    tracing is disabled or no context is bound, in a process without
+    the profiler hook."""
 
     __slots__ = ()
 
@@ -255,18 +265,15 @@ class _NullSpan:
     def __exit__(self, *exc: Any) -> bool:
         return False
 
-    def set_attrs(self, **attrs: Any) -> None:
-        pass
-
 
 _NULL_SPAN = _NullSpan()
 
 
 class _LiveSpan:
-    #: ``span_id`` and ``start`` are public — callers that need to hang
-    #: synthesized children off a live span (the worker's device/walk
-    #: spans under "execute") read them after ``__enter__``
-    __slots__ = ("_ctx", "_name", "_attrs", "_prev", "span_id", "start", "_t0")
+    """A span recorded into the bound context, inside the profiler
+    annotation of the same name when the hook is installed."""
+
+    __slots__ = ("_ctx", "_name", "_attrs", "_prev", "_ann", "span_id", "start", "_t0")
 
     def __init__(self, ctx: TraceContext, name: str, attrs: dict):
         self._ctx = ctx
@@ -274,15 +281,16 @@ class _LiveSpan:
         self._attrs = attrs
 
     def __enter__(self) -> "_LiveSpan":
+        ann = _annotation
+        self._ann = None if ann is None else ann(self._name)
+        if self._ann is not None:
+            self._ann.__enter__()
         self.span_id = new_span_id()
         self._prev = getattr(_tls, "span", None)
         _tls.span = self.span_id
         self.start = time.time()
         self._t0 = time.perf_counter()
         return self
-
-    def set_attrs(self, **attrs: Any) -> None:
-        self._attrs.update(attrs)
 
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> bool:
         duration = time.perf_counter() - self._t0
@@ -294,24 +302,43 @@ class _LiveSpan:
             parent_id=self._prev or self._ctx.root_id,
             span_id=self.span_id, **self._attrs,
         ))
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
 def span(name: str, **attrs: Any):
-    """Open a child span under the thread's ambient context. Returns
-    the shared no-op when tracing is off or no context is bound, so
-    call sites never branch."""
+    """Open a child span under the thread's ambient context.
+
+    With tracing off or no context bound this returns the profiler
+    annotation alone once the hook is installed (``attrs`` are not
+    passed on: the name reaches the trace exactly as written), else the
+    shared no-op — so call sites never branch. Hot-path spans sit at
+    per-call and per-chunk boundaries, never per row."""
     on = _override
     if on is None:
         on = _env_cached
         if on is None:
             on = _read_env()
-    if not on:
-        return _NULL_SPAN
-    ctx = getattr(_tls, "ctx", None)
+    ctx = getattr(_tls, "ctx", None) if on else None
     if ctx is None:
-        return _NULL_SPAN
+        ann = _annotation
+        return _NULL_SPAN if ann is None else ann(name)
     return _LiveSpan(ctx, name, attrs)
+
+
+def spanned(name: str) -> Callable:
+    """Decorator: every call of the function runs inside ``span(name)``."""
+
+    def wrap(fn: Callable) -> Callable:
+        @functools.wraps(fn)
+        def inner(*args: Any, **kwargs: Any) -> Any:
+            with span(name):
+                return fn(*args, **kwargs)
+
+        return inner
+
+    return wrap
 
 
 # ---------------------------------------------------------------------------

@@ -568,11 +568,14 @@ class JobProcessor:
             update(JobStatus.CMD_FAILED)
             return
 
-        # kept as a named object: after a successful execute the engine
-        # stats deltas are folded into device/walk child spans under it
-        exec_span = tracing.span("execute", module=job.get("module"))
+        # the engine's own spans (classifier.*, engine.*, device.*) nest
+        # under "execute", and reach the job's profile when one records
         try:
-            with timer.phase("execute"), maybe_device_profile(job_id), exec_span:
+            with (
+                timer.phase("execute"),
+                maybe_device_profile(job_id),
+                tracing.span("execute", module=job.get("module")),
+            ):
                 # chaos lever: fail (or delay) this chunk's execution —
                 # detail carries the job id so a plan can poison one job
                 fault_point("executor.run", detail=job_id)
@@ -624,9 +627,6 @@ class JobProcessor:
             perf["output_bytes"] = len(output)
             perf.update(self._engine_perf_delta())
             perf.update(self._scan_perf_extra)
-            ctx = tracing.current_context()
-            if ctx is not None:
-                self._synth_engine_spans(ctx, perf, exec_span)
             # this worker's non-closed breakers (transport + device)
             # ride the perf fields to the server, so /get-statuses
             # shows remote-fleet degradation the server-side /healthz
@@ -681,38 +681,6 @@ class JobProcessor:
         )
         print(f"server unreachable; spooled finished chunk {job_id}")
 
-    def _synth_engine_spans(self, ctx, perf: dict, exec_span) -> None:
-        """Fold the engine's accumulated device/walk timings into child
-        spans of the execute span. The device holds no wall clock of
-        its own, so the phases are laid out contiguously from the
-        execute start; the DURATIONS are the authoritative EngineStats
-        deltas (device phase A/B included when the engine reports
-        them), which is what the critical-path attribution consumes."""
-        parent = getattr(exec_span, "span_id", None)
-        start = getattr(exec_span, "start", None)
-        if parent is None or start is None:
-            return
-        device_s = perf.get("device_s") or 0.0
-        walk_s = perf.get("host_confirm_s") or 0.0
-        if device_s > 0:
-            dev_id = ctx.add_synth(
-                "device", start, device_s, parent_id=parent,
-                rows=perf.get("rows"), mesh=perf.get("mesh"),
-                pipeline=perf.get("pipeline"),
-            )
-            pa = perf.get("phase_a_s") or 0.0
-            pb = perf.get("phase_b_s") or 0.0
-            if pa > 0:
-                ctx.add_synth(
-                    "device.phase_a", start, pa, parent_id=dev_id
-                )
-            if pb > 0:
-                ctx.add_synth(
-                    "device.phase_b", start + pa, pb, parent_id=dev_id
-                )
-        if walk_s > 0:
-            ctx.add_synth("walk", start + device_s, walk_s, parent_id=parent)
-
     def _mark_engine_stats(self, engine) -> None:
         """Snapshot the cumulative engine counters at job start so
         :meth:`_engine_perf_delta` can report this job's delta."""
@@ -722,8 +690,6 @@ class JobProcessor:
             ds.rows,
             ds.device_seconds,
             ds.host_confirm_seconds,
-            getattr(ds, "phase_a_seconds", 0.0),
-            getattr(ds, "phase_b_seconds", 0.0),
             ds.device_compile_seconds,
             ds.device_faults,
             ds.degraded_batches,
@@ -735,7 +701,7 @@ class JobProcessor:
         mark = self._engine_stats_mark
         if mark is None:
             return {}
-        engine, rows0, dev0, confirm0, pa0, pb0, comp0, faults0, degr0 = mark
+        engine, rows0, dev0, confirm0, comp0, faults0, degr0 = mark
         ds = engine.stats
         out = {
             "rows": ds.rows - rows0,
@@ -748,17 +714,6 @@ class JobProcessor:
             "degraded_batches": ds.degraded_batches - degr0,
             **device_perf(),
         }
-        # split-phase device attribution, when the matcher reported it
-        # (single-device compacted path); feeds the device.phase_a/b
-        # child spans. Tracing-gated: with tracing off the perf wire
-        # payload must stay byte-identical to the untraced build.
-        if tracing.enabled():
-            pa = round(getattr(ds, "phase_a_seconds", 0.0) - pa0, 6)
-            pb = round(getattr(ds, "phase_b_seconds", 0.0) - pb0, 6)
-            if pa > 0:
-                out["phase_a_s"] = pa
-            if pb > 0:
-                out["phase_b_s"] = pb
         mesh = getattr(engine, "mesh", None)
         if mesh is not None:
             out["mesh"] = "x".join(
@@ -1114,8 +1069,8 @@ class JobProcessor:
             sched.config.qos_deadline_ms = self.cfg.qos_deadline_ms
             sched.config.max_age_ms = self.cfg.sched_max_age_ms
             # "sched" = the continuous-batching drive window (planning,
-            # coalescing, deadline flushes); the engine's device/walk
-            # attribution rides the synthesized child spans instead
+            # coalescing, deadline flushes); the engine's device.* and
+            # engine.* spans nest under it, the offloaded walk's included
             with tracing.span("sched", qos=qos, pipeline="on"):
                 for ci, res in enumerate(
                     sched.run(payloads, decode=decode, qos=qos)
