@@ -142,6 +142,10 @@ class EngineStats:
     walk_batch_rounds: int = 0
     walk_precompute_seconds: float = 0.0
     walk_pool_threads: int = 0
+    # direct path (``match``): chunks whose walk ran while the next
+    # chunk's kernels were already in flight — chunks − 1 per call when
+    # every chunk launches asynchronously (begin_packed in flight)
+    overlapped_batches: int = 0
 
 
 def _bit(packed: np.ndarray, b: int, i: int) -> bool:
@@ -1166,15 +1170,41 @@ class MatchEngine:
                 else RowMatches(template_ids=[], extractions={})
                 for r in responses
             ]
-        out: list[RowMatches] = []
         chunks = [
             responses[s : s + self.batch_rows]
             for s in range(0, len(responses), self.batch_rows)
         ]
+        if len(chunks) <= 1:  # nothing to overlap the walk with
+            return [
+                m
+                for rows in chunks
+                for m in self.rowmatches_from_packed(
+                    self.match_packed(rows), len(rows)
+                )
+            ]
+        # one-deep software pipeline: launch chunk i's kernels, THEN walk
+        # chunk i-1, so the device runs chunk i's phase A right behind
+        # chunk i-1's phase B and the walk hides under chunk i's phase B.
+        # Where begin_packed defers (no native encoder, no async
+        # dispatch) finish_packed does all of a chunk's work: serial.
+        # Live at once: chunk i-1 walking, chunk i on the device, chunk
+        # i+1 encoding on the helper — within the recycled pools' depth
+        # of 8, and each PackedMatches is consumed before the next chunk.
+        out: list[RowMatches] = []
+        prev = None  # (rows, handle) of the chunk launched last
         for rows, pre in self._iter_encoded(chunks):
-            packed = self.match_packed(rows, pre=pre)
-            out.extend(self.rowmatches_from_packed(packed, len(rows)))
+            handle = self.begin_packed(rows, pre)
+            if prev is not None:
+                if handle[3] is not None:  # this chunk's kernels in flight
+                    self.stats.overlapped_batches += 1
+                out.extend(self._finish_rows(*prev))
+            prev = (rows, handle)
+        out.extend(self._finish_rows(*prev))
         return out
+
+    def _finish_rows(self, rows, handle) -> list:
+        """Per-row results of one :meth:`begin_packed` chunk."""
+        return self.rowmatches_from_packed(self.finish_packed(handle), len(rows))
 
     @tracing.spanned("engine.assemble")
     def rowmatches_from_packed(self, packed: PackedMatches, n: int) -> list:
@@ -1245,10 +1275,6 @@ class MatchEngine:
         overlapping the current chunk's device dispatch + confirmation
         (the encode is the feed ceiling at device rates; the device
         wait releases the GIL, so one helper thread recovers it)."""
-        if len(chunks) <= 1:
-            for c in chunks:
-                yield c, None
-            return
         from concurrent.futures import ThreadPoolExecutor
 
         if not self._backend_ready:
@@ -2473,9 +2499,10 @@ class MatchEngine:
         rows) and launch the device kernel asynchronously. Returns an
         opaque in-flight handle for :meth:`finish_packed`.
 
-        This is the continuous-batching scheduler's submission surface:
-        with bounded in-flight handles the device crunches batch i+1
-        while the host walks batch i. The split is only effective on
+        This is the submission surface of the continuous-batching
+        scheduler and of :meth:`match`'s multi-chunk direct path: with
+        bounded in-flight handles the device crunches batch i+1 while
+        the host walks batch i. The split is only effective on
         the native-memo single-device path (DeviceDB.dispatch); other
         configurations defer all work to finish time — same results,
         no overlap."""

@@ -85,6 +85,11 @@ def _gauges() -> dict:
                 "swarm_engine_device_faults",
                 "Device-path failures observed (compile/OOM/dispatch)",
             ),
+            overlapped_batches=g(
+                "swarm_engine_overlapped_batches",
+                "Direct-path chunks walked while the next chunk's "
+                "kernels were in flight",
+            ),
         )
     return _G
 
@@ -106,7 +111,7 @@ def _collect() -> None:
     with _lock:
         engines = list(_engines)
     rows = batches = confirm_pairs = always_pairs = overflow = memo = 0
-    degraded = degraded_batches = device_faults = 0
+    degraded = degraded_batches = device_faults = overlapped = 0
     dev_s = confirm_s = compile_s = 0.0
     capacity = 0
     walk_pairs = walk_rounds = walk_pool = 0
@@ -126,6 +131,7 @@ def _collect() -> None:
         capacity += s.batches * getattr(eng, "batch_rows", 0)
         degraded_batches += getattr(s, "degraded_batches", 0)
         device_faults += getattr(s, "device_faults", 0)
+        overlapped += getattr(s, "overlapped_batches", 0)
         walk_pairs += getattr(s, "walk_batched_pairs", 0)
         walk_rounds += getattr(s, "walk_batch_rounds", 0)
         walk_pre_s += getattr(s, "walk_precompute_seconds", 0.0)
@@ -158,6 +164,7 @@ def _collect() -> None:
     g["degraded"].set(degraded)
     g["degraded_batches"].set(degraded_batches)
     g["device_faults"].set(device_faults)
+    g["overlapped_batches"].set(overlapped)
 
 
 def engine_stats_snapshot(engine) -> dict:

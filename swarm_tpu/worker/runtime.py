@@ -693,6 +693,7 @@ class JobProcessor:
             ds.device_compile_seconds,
             ds.device_faults,
             ds.degraded_batches,
+            ds.overlapped_batches,
         )
 
     def _engine_perf_delta(self) -> dict:
@@ -701,7 +702,7 @@ class JobProcessor:
         mark = self._engine_stats_mark
         if mark is None:
             return {}
-        engine, rows0, dev0, confirm0, comp0, faults0, degr0 = mark
+        engine, rows0, dev0, confirm0, comp0, faults0, degr0, ovl0 = mark
         ds = engine.stats
         out = {
             "rows": ds.rows - rows0,
@@ -712,6 +713,8 @@ class JobProcessor:
             # job served from the CPU oracle instead of the device
             "device_faults": ds.device_faults - faults0,
             "degraded_batches": ds.degraded_batches - degr0,
+            # chunks whose walk ran under the next chunk's kernels
+            "overlapped_batches": ds.overlapped_batches - ovl0,
             **device_perf(),
         }
         mesh = getattr(engine, "mesh", None)

@@ -100,6 +100,35 @@ def test_classify_spans_reach_the_profiler_trace_nested_in_the_call(
     assert len(per_chunk) == 3  # per chunk, never per row
 
 
+def test_direct_path_launches_the_next_chunk_before_walking_this_one(
+    classifier, tmp_path
+):
+    """Three chunks: chunk i+1's ``device.launch`` starts before chunk
+    i's ``engine.confirm_walk`` on the profiler trace, and each chunk but
+    the last counts in ``overlapped_batches``. One chunk runs the serial
+    call: encoded inline, nothing counted."""
+    stats = classifier.engine.stats
+    rows, sent = _rows("overlap")
+    n0 = stats.overlapped_batches
+    with jax.profiler.trace(str(tmp_path / "three")):
+        classifier.classify(rows, sent)
+    assert stats.overlapped_batches == n0 + 2
+    order = [n for _s, n in sorted(
+        (s, n) for n, s, _e in _host_events(str(tmp_path / "three"))
+        if n in ("device.launch", "engine.confirm_walk"))]
+    launch, walk = "device.launch", "engine.confirm_walk"
+    # L0 L1 W0 L2 W1 W2: launch chunk i+1, then walk chunk i
+    assert order == [launch, launch, walk, launch, walk, walk]
+
+    rows, sent = _rows("one")
+    with jax.profiler.trace(str(tmp_path / "one")):
+        classifier.classify(rows[:10], sent[:10])
+    assert stats.overlapped_batches == n0 + 2
+    names = [n for n, _s, _e in _host_events(str(tmp_path / "one"))]
+    assert names.count("device.launch") == names.count("engine.confirm_walk") == 1
+    assert "engine.encode_wait" in names and "engine.encode_chunk" not in names
+
+
 def test_scheduler_path_records_the_same_spans(tmp_path):
     """``pipeline="on"``: begin_packed / finish_packed reach the same
     shared methods; the scheduler encodes ahead itself, so no
@@ -151,6 +180,11 @@ def test_operator_tracing_lands_the_same_spans_with_real_starts(classifier):
     for a, b in zip(walks, walks[1:]):
         assert a["start"] + a["duration_s"] <= b["start"] + 1e-3
     assert walks[-1]["start"] <= veto["start"]
+    # the direct path launches chunk i+1 before it walks chunk i
+    launches = sorted(s["start"] for s in by_name["device.launch"])
+    assert len(launches) == 3
+    for launch, walk in zip(launches[1:], walks):
+        assert launch < walk["start"]
     assert not hasattr(classifier.engine.stats, "phase_a_seconds")
 
 
@@ -270,6 +304,9 @@ def test_worker_service_job_profile_carries_the_spans(tmp_path, monkeypatch):
         proc = JobProcessor(Config(**{**cfg.__dict__, "max_jobs": 1, "worker_id": "sp-w"}))
         proc.process_jobs()
         assert proc.jobs_done == 1
+        (job,) = client.get_statuses()["jobs"].values()
+        # one row is one chunk: nothing to walk under a next chunk
+        assert job["perf"]["overlapped_batches"] == 0
     finally:
         srv.shutdown()
         banner.shutdown()
