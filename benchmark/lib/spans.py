@@ -10,7 +10,8 @@ from __future__ import annotations
 
 from lib import trace as tr
 
-#: every span the program opens on the census path
+#: every span the program opens on its matching path: the service
+#: classifier's, and the engine's and device's that every MatchEngine opens
 PROGRAM = (
     "classifier.classify",
     "classifier.veto_walk",
@@ -84,6 +85,21 @@ def exposed_ms(run, names) -> float | None:
     if ivs is None or not gaps:
         return None
     return _exposed_ns(gaps, ivs) / run.calls / 1e6
+
+
+def label(gap: tuple, found: list, default: str = "harness") -> str:
+    """The name that holds most of ``gap``: each instant of it goes to
+    the innermost (shortest) of the spans in ``found`` open then, or to
+    ``default`` where none is, and the name with the most time wins."""
+    lo, hi = gap
+    inside = [(e - s, n, max(s, lo), min(e, hi)) for n, s, e in found if s < hi and e > lo]
+    cuts = sorted({lo, hi} | {a for *_x, a, _b in inside} | {b for *_x, b in inside})
+    held: dict = {}
+    for a, b in zip(cuts, cuts[1:]):
+        open_ = [(d, n) for d, n, s, e in inside if s <= a and b <= e]
+        name = min(open_)[1] if open_ else default
+        held[name] = held.get(name, 0) + b - a
+    return max(held, key=held.get)
 
 
 def cover(run, names=PROGRAM) -> dict | None:

@@ -2,17 +2,8 @@
 
 from __future__ import annotations
 
+from lib import spans as sp
 from lib import trace as tr
-
-#: host spans of the harness (run.py) and what the host does inside each
-#: where no narrower span is open
-SPAN_LABELS = {
-    "classify": "classifier.veto_walk",
-    "engine.match": "engine.host_walk",
-    "engine.encode": "engine.encode",
-    "device.dispatch": "device.dispatch",
-    "device.collect": "device.collect",
-}
 
 
 def device_summary(run) -> tuple:
@@ -23,11 +14,8 @@ def device_summary(run) -> tuple:
         return 0.0, window_s, {"device_ops": [], "idle_gaps": []}
     busy_s = sum(tr.busy_ns(run.trace, d, t0, t1) for d in devices) / len(devices) / 1e9
     ops = tr.top_names(run.trace, devices, tr.MODULES_LINE, t0, t1, 10)
-    spans = [
-        (SPAN_LABELS[n], s, e)
-        for n, s, e in tr.host_spans(run.trace, SPAN_LABELS)
-    ]
+    found = tr.host_spans(run.trace, sp.PROGRAM)
     gaps = tr.gaps(tr.busy(run.trace, devices[0], t0, t1), t0, t1)
     longest = sorted(gaps, key=lambda g: g[0] - g[1])[:10]
-    idle = [[tr.attribute(g, spans, "harness"), (g[1] - g[0]) / 1e9] for g in longest]
+    idle = [[sp.label(g, found), (g[1] - g[0]) / 1e9] for g in longest]
     return busy_s, window_s, {"device_ops": ops, "idle_gaps": idle}

@@ -8,8 +8,8 @@ event on the plane's ``XLA Modules`` line, and the device counts as busy
 while one runs; an operation inside it is an event on the ``XLA Ops``
 line, which is not read: the matcher's while loops put some 170,000 op
 events a second on it. Host and device events share one clock. Host
-spans are the harness's own ``TraceAnnotation`` events, found by name on
-any host line.
+spans (the program's own and the harness's ``bench.window``) are
+``TraceAnnotation`` events, found by name on any host line.
 """
 
 from __future__ import annotations
@@ -118,10 +118,3 @@ def host_spans(trace: list, names: Iterable[str]) -> list:
         for p, _l, evs in trace if not DEVICE_PLANE.match(p)
         for n, s, d in evs if n in want
     ]
-
-
-def attribute(gap: tuple, spans: list, default: str) -> str:
-    """The innermost host span open at the middle of ``gap``."""
-    mid = (gap[0] + gap[1]) // 2
-    open_ = [(e - s, n) for n, s, e in spans if s <= mid < e]
-    return min(open_)[1] if open_ else default

@@ -1,8 +1,9 @@
 """The matcher's share of its bytes-bound floor: the least time the chip
-could take to read each call's unpadded banner bytes and write one
-verdict bit per row and directive, at the device's peak HBM bandwidth
+could take to read each call's unpadded input bytes and write one
+verdict bit per row and verdict (the driver's ``verdict_width``: the
+census DB's match directives), at the device's peak HBM bandwidth
 (lib/peaks.json), over the matcher's device time (kernel.match_ms). The
-floor comes from the traffic and the DB alone."""
+floor comes from the traffic and the reference alone."""
 
 from pathlib import Path
 
@@ -16,5 +17,5 @@ def read(run):
     if s is None or not bw or not run.calls:
         return None
     rows_per_call = run.rows / run.calls
-    floor_bytes = sum(run.call_bytes) + run.calls * rows_per_call * run.n_directives / 8
+    floor_bytes = sum(run.call_bytes) + run.calls * rows_per_call * run.verdict_width / 8
     return floor_bytes / bw / s * 100.0

@@ -1,17 +1,36 @@
 #!/usr/bin/env python3
-"""One run of one census cell: ``--workload --seed --seconds --trace``.
+"""One run of one cell: ``--workload --seed --seconds --trace``.
 
-The timed path is the worker's chip-facing service path,
-``ServiceClassifier.classify(rows, sent)`` (worker/runtime.py), driven
-closed-loop by one caller with back-to-back calls of the mix's
-``rows_per_call`` rows. Set-up builds the classifier as the worker does,
-with the lowered-DB cache and JAX's compile cache in fixed directories
-inside the checkout, warms the cell's shapes on warm-up calls, and
-generates the window's calls ahead. The window then runs ``--seconds`` of
-calls. Afterwards, outside set-up and the window, the benchmark's own
-reference (lib/reference.py) is built and a sample of the rows the
-window classified, drawn from the seed, is compared with it. The last
-stdout line is the result.
+The cell's configuration names a driver, ``benchmark/drivers/<driver>.py``,
+which knows the deployment; this file is the same for every deployment.
+Set-up builds the deployment (the lowered-DB cache and JAX's compile
+cache sit in fixed directories inside the checkout), warms the cell's
+shapes on warm-up calls of its own traffic, and generates the window's
+calls ahead. The window then runs ``--seconds`` of back-to-back calls of
+the timed path, one caller, closed loop. Afterwards, outside set-up and
+the window, the driver's plain reference is built and a sample of the
+rows the window answered, drawn from the seed, is compared with it. The
+last stdout line is the result.
+
+A driver module has two functions. ``build(root, config, mix, seed)``
+builds the program as the system does and returns the deployment:
+
+- ``program``: the object whose call the window times (``--control`` and
+  the tests' ``plant`` act on it), and ``engine``, its ``MatchEngine``,
+  whose ``EngineStats`` and matcher the runner reads;
+- ``call(stream, k)``: call ``k`` of the ``"warmup"`` or ``"window"``
+  stream, a pure function of the seed, as ``(inputs, rows, sizes)``:
+  what the timed call takes, each row as the reference reads it, and
+  each row's unpadded input bytes;
+- ``run(program, inputs)``: one call of the timed path, one output per
+  row it answered;
+- ``answer(output)``: what of an output has to equal the reference;
+- ``control()``: the control, put in the program's place.
+
+``reference(root, config)`` builds the plain reference, which imports
+nothing of the program: ``expected(row)`` is a row's answer, and
+``verdict_width`` the verdicts per row that the matcher's bytes floor
+counts.
 """
 
 from __future__ import annotations
@@ -28,7 +47,6 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 from dataclasses import dataclass, field  # noqa: E402
 from pathlib import Path  # noqa: E402
-from types import SimpleNamespace  # noqa: E402
 
 ROOT = Path(__file__).resolve().parent.parent
 HERE = Path(__file__).resolve().parent
@@ -36,8 +54,6 @@ CACHE = HERE / ".cache"
 sys.path.insert(0, str(HERE))
 
 from lib import spec as speclib  # noqa: E402
-from lib.reference import Reference, parse_db, probe_for_port  # noqa: E402
-from lib.traffic import WARMUP, WINDOW, Generator  # noqa: E402
 
 #: rows kept per call for the comparison, and the most compared in a run
 SAMPLE_PER_CALL = 128
@@ -63,13 +79,12 @@ class Run:
     calls: int = 0
     stats0: dict = field(default_factory=dict)
     stats1: dict = field(default_factory=dict)
-    spans_s: dict = field(default_factory=dict)
     trace: list | None = None
     window_ns: tuple = (0, 0)
     devices: list = field(default_factory=list)
     peaks: dict = field(default_factory=dict)
     call_bytes: list = field(default_factory=list)
-    n_directives: int = 0
+    verdict_width: int = 0
 
 
 def _stats(engine) -> dict:
@@ -81,41 +96,6 @@ def _stats(engine) -> dict:
         "device_faults": s.device_faults,
         "degraded_batches": s.degraded_batches,
     }
-
-
-FIELDS = ("service", "product", "version", "info", "cpe", "soft")
-
-
-def answer(info) -> tuple:
-    return (info.service, info.product, info.version, info.info,
-            tuple(info.cpe), bool(info.soft))
-
-
-class ControlClassifier:
-    """The reference in the program's place, its capture binding off."""
-
-    def __init__(self, db_text: str):
-        self.ref = Reference(db_text, capture=False)
-
-    def classify(self, rows, sent):
-        return [SimpleNamespace(host=r.host, port=r.port,
-                                **dict(zip(FIELDS, self.ref.classify(r.banner, s))))
-                for r, s in zip(rows, sent)]
-
-
-def _instrument(obj, attr: str, label: str, acc: dict, jax) -> None:
-    """Wrap ``obj.attr`` on the instance in a host span (trace runs)."""
-    fn = getattr(obj, attr)
-
-    def wrapped(*a, **kw):
-        t0 = time.perf_counter()
-        try:
-            with jax.profiler.TraceAnnotation(label):
-                return fn(*a, **kw)
-        finally:
-            acc[label] = acc.get(label, 0.0) + time.perf_counter() - t0
-
-    setattr(obj, attr, wrapped)
 
 
 def _record_shapes(matcher, shapes: set) -> None:
@@ -131,24 +111,19 @@ def _record_shapes(matcher, shapes: set) -> None:
     matcher.dispatch = wrapped
 
 
-def _to_rows(Response, call: list):
-    return ([Response(host=h, port=p, banner=b) for h, p, b, _s in call],
-            [s for _h, _p, _b, s in call])
-
-
-def _prepare(Response, call: list, seed: int, i: int, np):
-    """A window call: its rows, sent probes, the rows kept for the
-    comparison (a seeded sample and the longest) and its banner bytes."""
-    rows, sent = _to_rows(Response, call)
+def _prepare(dep, seed: int, i: int, np):
+    """Window call ``i``: its inputs and rows, the rows kept for the
+    comparison (a seeded sample and the longest), their sizes and the
+    call's input bytes."""
+    inputs, rows, sizes = dep.call("window", i)
     pick = np.random.default_rng([seed, 7, i]).choice(
         len(rows), size=min(SAMPLE_PER_CALL, len(rows)), replace=False)
-    lens = [len(b) for _h, _p, b, _s in call]
-    keep = sorted(set(pick.tolist()) | {int(np.argmax(lens))})
-    return rows, sent, keep, sum(lens)
+    keep = sorted(set(pick.tolist()) | {int(np.argmax(sizes))})
+    return inputs, rows, sizes, keep, sum(sizes)
 
 
 def run_cell(argv=None, require_tpu: bool = True, plant=None, root: Path = ROOT) -> int:
-    """One run; returns the exit code. ``plant(classifier)`` may break the
+    """One run; returns the exit code. ``plant(program)`` may break the
     timed path after set-up, ``require_tpu=False`` lets a run use the CPU
     and ``root`` names another tree's BENCHMARK.json and data: the tests'
     hooks."""
@@ -158,8 +133,8 @@ def run_cell(argv=None, require_tpu: bool = True, plant=None, root: Path = ROOT)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--control", action="store_true",
-                    help="the control: the reference without capture binding "
-                         "in the program's place (not a benchmark run)")
+                    help="the driver's control in the program's place "
+                         "(not a benchmark run)")
     args = ap.parse_args(argv)
     try:
         result = _run(args, root, require_tpu, plant)
@@ -197,16 +172,11 @@ def _run(args, root: Path, require_tpu: bool, plant) -> dict:
     if require_tpu and kind not in peaks_all["devices"]:
         raise RunError(f"device kind {kind!r} is not in lib/peaks.json")
 
-    from swarm_tpu.fingerprints.model import Response
-    from swarm_tpu.ops.service import ServiceClassifier
     from swarm_tpu.utils.xlacache import enable_compilation_cache
 
     enable_compilation_cache()  # as the worker does at start-up
-    db_text = (root / cfg["db"]).read_text(encoding="latin-1")
-    probes = parse_db(db_text, matches=False)  # ports and rarities alone
-    gen = Generator(root, mix, args.seed, lambda port: probe_for_port(probes, port))
-    clf = ServiceClassifier(db_path=str(root / cfg["db"]), mesh=cfg["mesh"])
-    engine = clf.engine
+    dep = cell.driver.build(root, cfg, mix, args.seed)
+    engine = dep.engine
     engine._resolve_backend()
     matcher = engine.sharded if engine.sharded is not None else engine.device
     shapes: set = set()
@@ -218,7 +188,7 @@ def _run(args, root: Path, require_tpu: bool, plant) -> dict:
     while not args.control and (k < mix["warmup_calls"] or quiet < mix["warmup_quiet_calls"]):
         before = engine.stats.device_compiles
         t0 = time.perf_counter()
-        clf.classify(*_to_rows(Response, gen.call(WARMUP, k)))
+        dep.run(dep.program, dep.call("warmup", k)[0])
         warm_t.append(time.perf_counter() - t0)
         quiet = quiet + 1 if engine.stats.device_compiles == before else 0
         k += 1
@@ -229,27 +199,17 @@ def _run(args, root: Path, require_tpu: bool, plant) -> dict:
     pace = sorted(warm_t[-max(1, mix["warmup_quiet_calls"]):]) or [1.0]
     per_call = pace[len(pace) // 2]
     n_ahead = math.ceil(args.seconds / per_call * 1.3) + 2
-    ahead = [_prepare(Response, gen.call(WINDOW, i), args.seed, i, np)
-             for i in range(n_ahead)]
+    ahead = [_prepare(dep, args.seed, i, np) for i in range(n_ahead)]
 
-    if args.control:
-        clf_run = ControlClassifier(db_text)
-    else:
-        clf_run = clf
+    program = dep.control() if args.control else dep.program
     if plant is not None:
-        plant(clf_run)
+        plant(program)
     seconds = min(args.seconds, TRACE_SECONDS) if args.trace else args.seconds
     run = Run(workload=args.workload, seconds=seconds,
               peaks=peaks_all["devices"].get(kind, {}),
               devices=[d.id for d in used])
     trace_dir = CACHE / "trace"
     if args.trace:
-        for obj, attr, label in (
-            (clf_run, "classify", "classify"), (engine, "match", "engine.match"),
-            (engine, "encode_packed", "engine.encode"),
-            (matcher, "dispatch", "device.dispatch"), (matcher, "collect", "device.collect"),
-        ):
-            _instrument(obj, attr, label, run.spans_s, jax)
         shutil.rmtree(trace_dir, ignore_errors=True)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
@@ -257,7 +217,7 @@ def _run(args, root: Path, require_tpu: bool, plant) -> dict:
         jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
 
     # ---------------------------------------------------------------- window
-    kept: list = []  # (call, row, banner, sent, host:port, (answer, answer's host:port))
+    kept: list = []  # (call, row index, row, size, the program's answer)
     missing = failed = generated_late = 0
     run.stats0 = _stats(engine)
     win0 = time.perf_counter()
@@ -266,20 +226,19 @@ def _run(args, root: Path, require_tpu: bool, plant) -> dict:
         i = 0
         while True:
             if i < len(ahead):
-                rows, sent, keep, nbytes = ahead[i]
+                inputs, rows, sizes, keep, nbytes = ahead[i]
             else:
-                rows, sent, keep, nbytes = _prepare(
-                    Response, gen.call(WINDOW, i), args.seed, i, np)
+                inputs, rows, sizes, keep, nbytes = _prepare(dep, args.seed, i, np)
                 generated_late += 1
             bad0 = engine.stats.device_faults + engine.stats.degraded_batches
-            out = clf_run.classify(rows, sent)
+            out = dep.run(program, inputs)
             t1 = time.perf_counter()
             if engine.stats.device_faults + engine.stats.degraded_batches != bad0:
                 failed += len(rows)
             missing += max(0, len(rows) - len(out))
             for j in keep:
-                got = (answer(out[j]), (out[j].host, out[j].port)) if j < len(out) else None
-                kept.append((i, j, rows[j].banner, sent[j], (rows[j].host, rows[j].port), got))
+                got = dep.answer(out[j]) if j < len(out) else None
+                kept.append((i, j, rows[j], sizes[j], got))
             run.rows += len(rows)
             run.call_bytes.append(nbytes)
             i += 1
@@ -304,7 +263,7 @@ def _run(args, root: Path, require_tpu: bool, plant) -> dict:
             raise RunError("the trace holds no bench.window span")
         run.window_ns = (win[0][1], win[0][2])
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in used)
-    del clf, clf_run, engine, matcher
+    del dep, program, engine, matcher
 
     # ------------------------------------------------------------- checks
     print(f"window: {run.calls} calls, {run.rows} rows, {run.window_s} s; "
@@ -317,22 +276,22 @@ def _run(args, root: Path, require_tpu: bool, plant) -> dict:
     print("encoded batch shapes (streams, phase-B width): "
           + "; ".join(f"{dict(s)} k={k}" for s, k in sorted(shapes, key=str)), file=sys.stderr)
     t_ref = time.perf_counter()
-    ref = Reference(db_text)
-    run.n_directives = ref.n_directives
+    ref = cell.driver.reference(root, cfg)
+    run.verdict_width = ref.verdict_width
     rng = np.random.default_rng([args.seed, 8])
-    longest = max(range(len(kept)), key=lambda j: len(kept[j][2]))
+    longest = max(range(len(kept)), key=lambda j: kept[j][3])
     chosen = set(rng.choice(len(kept), size=min(SAMPLE_MAX, len(kept)), replace=False).tolist())
     chosen.add(longest)
     mismatched = 0
     shown = 0
     for j in sorted(chosen):
-        _i, _r, banner, sent_probe, where, got = kept[j]
-        want = ref.classify(banner, sent_probe)
-        if got != (want, where):
+        call, r, row, size, got = kept[j]
+        want = ref.expected(row)
+        if got != want:
             mismatched += 1
             if shown < 5:
                 shown += 1
-                print(f"mismatch call {_i} row {_r} ({sent_probe}, {len(banner)} B): "
+                print(f"mismatch call {call} row {r} ({size} B): "
                       f"program {got} reference {want}", file=sys.stderr)
     print(f"reference: built and {len(chosen)} rows compared in "
           f"{time.perf_counter() - t_ref} s", file=sys.stderr)

@@ -21,7 +21,7 @@ def tree_with_tiny_cell(tmp: Path) -> Path:
     test-sized configuration ``tiny-full``, mix ``tiny-fresh``, metric
     ``test.rows_per_call`` and a ``tiny`` cell in BENCHMARK.json."""
     (tmp / "swarm_tpu").symlink_to(REPO / "swarm_tpu")
-    for sub in ("configs", "traffic", "metrics", "lib"):
+    for sub in ("configs", "traffic", "metrics", "lib", "drivers"):
         shutil.copytree(BENCH / sub, tmp / "benchmark" / sub,
                         ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(DATA / "tiny-full.json", tmp / "benchmark" / "configs" / "tiny-full.json")

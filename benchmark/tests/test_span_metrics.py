@@ -2,9 +2,8 @@
 
 Device 0 runs three programs in a 100 ms window; the host lines hold the
 program's spans. The 20-50 ms gap straddles the engine's walk and the
-classifier's veto walk: the readers split it by intersection, where the
-midpoint rule of ``lib.summary``'s labels books all of it to the span
-open at 35 ms.
+classifier's veto walk: the readers split it by intersection, and
+``breakdown`` names it after the span that holds most of it.
 """
 
 from pathlib import Path
@@ -59,12 +58,12 @@ def test_gaps_are_split_by_intersection_not_by_midpoint():
     run = _run()
     gaps = tr.gaps(tr.busy(TRACE, 0, 0, 100 * MS), 0, 100 * MS)
     assert gaps == [(0, 10 * MS), (20 * MS, 50 * MS), (60 * MS, 90 * MS)]
-    # the midpoint rule books both long gaps, 60 ms, to the veto walk
-    open_spans = [s for s in tr.host_spans(TRACE, ["classifier.veto_walk",
-                                                    "engine.confirm_walk",
-                                                    "engine.assemble"])]
-    assert [tr.attribute(g, open_spans, "-") for g in gaps[1:]] == [
-        "classifier.veto_walk", "classifier.veto_walk"]
+    # each gap named after the innermost span that holds most of it: 13
+    # ms of veto walk against 10 of confirm walk in the first; in the
+    # second 15 ms of each, and the first to come wins the tie
+    found = tr.host_spans(TRACE, spans.PROGRAM)
+    assert [spans.label(g, found) for g in gaps] == [
+        "engine.encode_chunk", "classifier.veto_walk", "engine.confirm_walk"]
     # intersection: 13 + 15 ms to the classifier, 8 + 15 + 15 to the engine
     assert _read("classifier.exposed_ms", run) == pytest.approx(14.0)
     assert _read("engine.exposed_ms", run) == pytest.approx(19.0)

@@ -1,6 +1,7 @@
 """The trace reduction on a small trace: busy union, idle share, kernel
 sums and gap attribution."""
 
+from lib import spans as sp
 from lib import trace as tr
 
 MS = 1_000_000
@@ -38,6 +39,10 @@ def test_gaps_are_attributed_to_the_innermost_open_span():
     gaps = tr.gaps(tr.busy(TRACE, 0, t0, t1), t0, t1)
     assert gaps == [(5 * MS, 10 * MS), (15 * MS, 20 * MS), (30 * MS, 60 * MS), (65 * MS, 95 * MS)]
     spans = tr.host_spans(TRACE, ["classify", "engine.match"])
-    labels = [tr.attribute(g, spans, "harness") for g in gaps]
+    labels = [sp.label(g, spans) for g in gaps]
+    # (30, 60): 18 ms of engine.match, then 12 of classify alone
     assert labels == ["classify", "engine.match", "engine.match", "classify"]
-    assert tr.attribute((90 * MS, 94 * MS), spans, "harness") == "harness"
+    assert sp.label((90 * MS, 94 * MS), spans) == "harness"
+    # (44, 100): 4 ms of engine.match, 37 of classify, 15 of neither
+    assert sp.label((44 * MS, 100 * MS), spans) == "classify"
+    assert sp.label((70 * MS, 110 * MS), spans, "loop") == "loop"
