@@ -5,22 +5,37 @@ The timed path is the worker's chip-facing service path,
 the worker builds it from the configuration's ``db`` and ``mesh``. A
 call's rows come from the census generator (lib/traffic.py), each with
 the probe a scan sends to its port, which set-up picks from the DB's
-Probe lines alone. The reference is lib/reference.py, a plain
-nmap-service-probes walker that imports nothing of the program; the
-control is that walker with its capture binding off, in the program's
-place.
+Probe lines alone. The classifier's ``MatchEngine`` gives the counters
+and the encoded shapes (lib/matchengine.py). The reference is
+lib/reference.py, a plain nmap-service-probes walker that imports
+nothing of the program; the control is that walker with its capture
+binding off, in the program's place.
 """
 
 from __future__ import annotations
 
 from types import SimpleNamespace
 
+from lib.matchengine import EngineProbe
 from lib.reference import Reference, parse_db, probe_for_port
 from lib.traffic import WARMUP, WINDOW, Generator
 
 #: a row's answer, as ``ServiceInfo`` and the reference give it
 FIELDS = ("service", "product", "version", "info", "cpe", "soft")
 STREAMS = {"warmup": WARMUP, "window": WINDOW}
+#: every span the census path opens: the service classifier's, and the
+#: engine's and device's that every MatchEngine opens
+SPANS = (
+    "classifier.classify",
+    "classifier.veto_walk",
+    "engine.encode_wait",
+    "engine.encode_chunk",
+    "engine.confirm_walk",
+    "engine.assemble",
+    "device.launch",
+    "device.phase_a_wait",
+    "device.result_wait",
+)
 
 
 def _db_text(root, config) -> str:
@@ -49,7 +64,8 @@ class Census:
         probes = parse_db(self.db_text, matches=False)  # ports and rarities alone
         self.gen = Generator(root, mix, seed, lambda port: probe_for_port(probes, port))
         self.program = ServiceClassifier(db_path=str(root / config["db"]), mesh=config["mesh"])
-        self.engine = self.program.engine
+        probe = EngineProbe(self.program.engine)
+        self.stats, self.notes = probe.stats, probe.notes
 
     def call(self, stream: str, k: int) -> tuple:
         rows = self.gen.call(STREAMS[stream], k)

@@ -2,27 +2,15 @@
 
 The program opens its spans with ``telemetry/tracing.py`` ``span()``,
 which reaches the profiler trace as a host event of the same name, on
-the clock of the device planes. A program without those spans leaves
-the readers here nothing to read: they return None.
+the clock of the device planes. Each reader names the spans it reads;
+the spans a deployment opens are its driver's ``SPANS``. A program
+without the named spans leaves the readers nothing to read: they
+return None.
 """
 
 from __future__ import annotations
 
 from lib import trace as tr
-
-#: every span the program opens on its matching path: the service
-#: classifier's, and the engine's and device's that every MatchEngine opens
-PROGRAM = (
-    "classifier.classify",
-    "classifier.veto_walk",
-    "engine.encode_wait",
-    "engine.encode_chunk",
-    "engine.confirm_walk",
-    "engine.assemble",
-    "device.launch",
-    "device.phase_a_wait",
-    "device.result_wait",
-)
 
 
 def _clipped(run, names) -> list | None:
@@ -102,7 +90,7 @@ def label(gap: tuple, found: list, default: str = "harness") -> str:
     return max(held, key=held.get)
 
 
-def cover(run, names=PROGRAM) -> dict | None:
+def cover(run, names) -> dict | None:
     """What the named spans say of the window, per call: the device's
     idle ms, the share of it inside the union of all the spans, the
     spans opened, and for each name its ms, the idle ms it covers and

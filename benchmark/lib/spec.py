@@ -86,7 +86,10 @@ def _driver(root: Path, config: dict, where: str) -> ModuleType:
     path = root / "benchmark" / "drivers" / f"{config['driver']}.py"
     if not path.is_file():
         raise SpecError(f"no driver {path.relative_to(root)}")
-    return _module(path, "driver", config["driver"])
+    mod = _module(path, "driver", config["driver"])
+    if not isinstance(getattr(mod, "SPANS", None), tuple):
+        raise SpecError(f"driver {path.relative_to(root)} names no SPANS tuple")
+    return mod
 
 
 def load(root: Path, workload: str) -> Cell:

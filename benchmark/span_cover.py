@@ -6,10 +6,11 @@
 
 The first form makes one ``--trace 1`` run of the cell through
 ``run.py`` and prints its result line, then a last line
-``{"span_cover": ...}``: ``lib/spans.py`` ``cover`` over the same
-window, that is the device's idle ms per call, the share of it inside
-the union of the program's spans, the spans opened per call, and per
-span name its ms, the idle ms it covers and its count. It is not a
+``{"span_cover": ...}``: ``lib/spans.py`` ``cover`` of the spans the
+cell's driver lists (``SPANS``) over the same window, that is the
+device's idle ms per call, the share of it inside the union of those
+spans, the spans opened per call, and per span name its ms, the idle ms
+it covers and its count. It is not a
 benchmark metric: it says how much of the idle time the metrics that
 read the spans can see.
 
@@ -61,7 +62,7 @@ def traced(argv, require_tpu: bool = True, root: Path = run.ROOT) -> tuple:
                               require_tpu=require_tpu, root=root)
     finally:
         speclib.reader = reader
-    return rc, out.getvalue(), spans.cover(seen[0]) if seen else None
+    return rc, out.getvalue(), spans.cover(seen[0], seen[0].program_spans) if seen else None
 
 
 def cost(n: int = 20_000) -> dict:

@@ -9,7 +9,19 @@ from __future__ import annotations
 
 import numpy as np
 
+from lib.matchengine import EngineProbe
+
 STREAMS = {"warmup": 1, "window": 2}
+#: the spans every MatchEngine opens
+SPANS = (
+    "engine.encode_wait",
+    "engine.encode_chunk",
+    "engine.confirm_walk",
+    "engine.assemble",
+    "device.launch",
+    "device.phase_a_wait",
+    "device.result_wait",
+)
 
 
 def _size(r) -> int:
@@ -45,7 +57,9 @@ class TemplateScan:
 
         self.mix, self.seed = mix, int(seed)
         templates, db = load_or_compile(str(root / config["corpus"]))
-        self.program = self.engine = MatchEngine(templates, db=db)
+        self.program = MatchEngine(templates, db=db)
+        probe = EngineProbe(self.program)
+        self.stats, self.notes = probe.stats, probe.notes
 
     def call(self, stream: str, k: int) -> tuple:
         calls = rows(self.mix, self.seed, stream, k)

@@ -28,6 +28,9 @@ def tree_with_tiny_cell(tmp: Path) -> Path:
     shutil.copy(DATA / "tiny-fresh.json", tmp / "benchmark" / "traffic" / "tiny-fresh.json")
     (tmp / "benchmark" / "metrics" / "test.rows_per_call.py").write_text(TINY_METRIC)
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    for m in bench["per_layer"]:  # the census at test size reads what census-fresh reads
+        if "census-fresh" in m.get("workloads", ()):
+            m["workloads"].append("tiny")
     bench["configs"].append({"name": "tiny-full", "source": "test",
                              "file": "benchmark/configs/tiny-full.json",
                              "reduced": [], "why": "test size"})

@@ -9,6 +9,7 @@ times of a traced census-fresh run on one v5e (PERF.md section 5): two
 import json
 from pathlib import Path
 
+from drivers.service_census import SPANS
 from lib import spans as sp
 from lib import trace as tr
 
@@ -81,7 +82,7 @@ def test_gaps_are_named_after_the_programs_spans():
     program span that holds most of it."""
     t0, t1 = _window(FRESH)
     gaps = tr.gaps(tr.busy(FRESH, 0, t0, t1), t0, t1)
-    found = tr.host_spans(FRESH, sp.PROGRAM)
+    found = tr.host_spans(FRESH, SPANS)
     named = {(round(a / MS), round(b / MS)): sp.label((a, b), found) for a, b in gaps}
     assert named == {
         (0, 16): "engine.encode_chunk",  # the helper encodes chunk 0

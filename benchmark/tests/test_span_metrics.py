@@ -11,6 +11,7 @@ from types import SimpleNamespace
 
 import pytest
 
+from drivers.service_census import SPANS
 from lib import spec as speclib
 from lib import spans
 from lib import trace as tr
@@ -61,7 +62,7 @@ def test_gaps_are_split_by_intersection_not_by_midpoint():
     # each gap named after the innermost span that holds most of it: 13
     # ms of veto walk against 10 of confirm walk in the first; in the
     # second 15 ms of each, and the first to come wins the tie
-    found = tr.host_spans(TRACE, spans.PROGRAM)
+    found = tr.host_spans(TRACE, SPANS)
     assert [spans.label(g, found) for g in gaps] == [
         "engine.encode_chunk", "classifier.veto_walk", "engine.confirm_walk"]
     # intersection: 13 + 15 ms to the classifier, 8 + 15 + 15 to the engine
@@ -105,7 +106,7 @@ def test_overlap_of_interval_lists():
 
 def test_cover_of_the_idle_time_by_the_programs_spans():
     run = _run()
-    cov = spans.cover(run)
+    cov = spans.cover(run, SPANS)
     # 70 ms idle over two calls, all of it inside classifier.classify
     assert cov["idle_ms_per_call"] == pytest.approx(35.0)
     assert cov["covered_share"] == pytest.approx(1.0)
@@ -124,10 +125,10 @@ def test_cover_of_the_idle_time_by_the_programs_spans():
 
 def test_cover_without_a_device_or_without_the_spans():
     host_only = [t for t in TRACE if not t[0].startswith("/device:")]
-    cov = spans.cover(_run(host_only))
+    cov = spans.cover(_run(host_only), SPANS)
     assert cov["idle_ms_per_call"] is None and cov["covered_share"] is None
     assert cov["spans_per_call"] == pytest.approx(6.5)
     assert cov["per_name"]["engine.assemble"]["idle_ms_per_call"] is None
     bare = [("/device:TPU:0", "XLA Modules", BUSY),
             ("/host:CPU", "python", [("bench.window", 0, 100 * MS)])]
-    assert spans.cover(_run(bare)) is None
+    assert spans.cover(_run(bare), SPANS) is None
