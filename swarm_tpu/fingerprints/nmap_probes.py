@@ -25,6 +25,7 @@ Format reference (publicly documented by nmap):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import re
 from pathlib import Path
 
@@ -52,7 +53,12 @@ class ServiceMatch:
     def compile(self) -> Optional[re.Pattern]:
         """Python re over raw bytes; None when the pattern uses PCRE
         constructs re lacks (those matches are skipped, counted by the
-        loader)."""
+        loader). Compiled once: the loader's check and the classifier
+        share it (re's own cache holds 512 patterns, a DB has ~12k)."""
+        return self._compiled
+
+    @functools.cached_property
+    def _compiled(self) -> Optional[re.Pattern]:
         f = re.DOTALL if "s" in self.flags else 0
         if "i" in self.flags:
             f |= re.IGNORECASE

@@ -199,9 +199,33 @@ def _native_encoder_available() -> bool:
     return _NATIVE_ENCODER
 
 
+def width_class(n: int, multiple: int = 512, cap: int = 4096) -> int:
+    """The encoded width of a stream whose longest row is ``n`` bytes:
+    the smallest multiple of ``multiple`` that holds it, never below
+    ``multiple`` and capped at ``cap`` (rows past the cap truncate on
+    device and are redone on the host). The one definition of the
+    width ladder: :func:`encode_batch` sizes its streams with it, the
+    scheduler's planner keys its buckets by it (sched/buckets.py) and
+    the engine's direct path orders a call's rows by it, so none of
+    them can disagree on what width a row encodes at."""
+    return max(multiple, min(cap, round_up(max(n, 1), multiple)))
+
+
+def row_width_class(
+    row: Response, multiple: int = 512, max_body: int = 4096,
+    max_header: int = 1024,
+) -> tuple:
+    """(body class, header class) of one row: the narrowest batch it
+    can ride in. "body" is the banner when one is set, as in
+    :func:`encode_batch`."""
+    return (
+        width_class(row.content_length, multiple, max_body),
+        width_class(len(row.header), multiple, max_header),
+    )
+
+
 def _width_for(lens: np.ndarray, cap: int, multiple: int = 128) -> int:
-    longest = int(lens.max()) if lens.size else 0
-    return max(multiple, min(cap, round_up(max(longest, 1), multiple)))
+    return width_class(int(lens.max()) if lens.size else 0, multiple, cap)
 
 
 def encode_batch(

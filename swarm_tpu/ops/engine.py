@@ -36,7 +36,9 @@ import numpy as np
 from swarm_tpu.fingerprints.compile import CompiledDB, compile_corpus
 from swarm_tpu.fingerprints.model import Response, Template
 from swarm_tpu.ops import cpu_ref, fastre
-from swarm_tpu.ops.encoding import _RotatingPool, encode_batch, round_up
+from swarm_tpu.ops.encoding import (
+    _RotatingPool, encode_batch, round_up, row_width_class,
+)
 from swarm_tpu.ops.match import DeviceDB
 from swarm_tpu.telemetry import tracing
 from swarm_tpu.telemetry.memo_export import L1_HITS, L1_MISSES
@@ -146,6 +148,11 @@ class EngineStats:
     # chunk's kernels were already in flight — chunks − 1 per call when
     # every chunk launches asynchronously (begin_packed in flight)
     overlapped_batches: int = 0
+    # direct path (``match``): chunks cut from rows whose width class
+    # lies below the call's widest, so they encode narrower than a chunk
+    # of the call's widest rows (the width order at work; counted at the
+    # cut, memo-served chunks too; 0 for a call of one chunk or class)
+    narrowed_chunks: int = 0
 
 
 def _bit(packed: np.ndarray, b: int, i: int) -> bool:
@@ -160,6 +167,10 @@ def _iter_set_bits(row_bytes: np.ndarray, limit: int) -> np.ndarray:
 
 
 _ROWDEP_VAR_RE = None
+
+#: the encoded streams' width ladder (encoding.width_class): coarse
+#: classes bound the set of compiled batch shapes
+_WIDTH_MULTIPLE = 512
 
 
 def _is_row_dependent(t: Template) -> bool:
@@ -1170,18 +1181,19 @@ class MatchEngine:
                 else RowMatches(template_ids=[], extractions={})
                 for r in responses
             ]
+        if len(responses) <= self.batch_rows:
+            # one chunk: nothing to overlap the walk with
+            if not responses:
+                return []
+            return self.rowmatches_from_packed(
+                self.match_packed(responses), len(responses)
+            )
+        order = self._width_order(responses)
+        responses = [responses[i] for i in order]
         chunks = [
             responses[s : s + self.batch_rows]
             for s in range(0, len(responses), self.batch_rows)
         ]
-        if len(chunks) <= 1:  # nothing to overlap the walk with
-            return [
-                m
-                for rows in chunks
-                for m in self.rowmatches_from_packed(
-                    self.match_packed(rows), len(rows)
-                )
-            ]
         # one-deep software pipeline: launch chunk i's kernels, THEN walk
         # chunk i-1, so the device runs chunk i's phase A right behind
         # chunk i-1's phase B and the walk hides under chunk i's phase B.
@@ -1200,7 +1212,41 @@ class MatchEngine:
                 out.extend(self._finish_rows(*prev))
             prev = (rows, handle)
         out.extend(self._finish_rows(*prev))
-        return out
+        back: list = [None] * len(out)
+        for pos, i in enumerate(order):
+            back[i] = out[pos]
+        return back
+
+    def _width_order(self, rows: Sequence[Response]) -> list:
+        """The order in which the direct path cuts a call of more than
+        one chunk: row indices stable-sorted by width class (body, then
+        header), widest first. (A call of one class keeps the caller's
+        order: the sort is stable.)
+
+        Each chunk encodes at its longest row's class, so in the
+        caller's order a few long rows spread over every chunk and pad
+        all the short ones to their width; ordered, the short rows share
+        narrow chunks (phase A's prefilter, phase B's streams and its
+        regex scan all grow with the width, and a narrow chunk's worst
+        row usually sets a lower candidate rung too). The widest chunk
+        goes first: its confirm walk, the longer one, then runs under
+        the next chunk's kernels, and the narrow chunk's shorter walk is
+        the one left after the last kernel. No answer depends on which
+        rows share a batch. Counts ``stats.narrowed_chunks``."""
+        n, size = len(rows), self.batch_rows
+        cls = [
+            row_width_class(
+                r, _WIDTH_MULTIPLE, self.max_body, self.max_header
+            )
+            for r in rows
+        ]
+        order = sorted(range(n), key=cls.__getitem__, reverse=True)
+        wide = (cls[order[0]][0], max(c[1] for c in cls))
+        for s in range(0, n, size):
+            chunk = order[s : s + size]
+            if (cls[chunk[0]][0], max(cls[i][1] for i in chunk)) != wide:
+                self.stats.narrowed_chunks += 1
+        return order
 
     def _finish_rows(self, rows, handle) -> list:
         """Per-row results of one :meth:`begin_packed` chunk."""
@@ -1517,7 +1563,7 @@ class MatchEngine:
                 # still a compile
                 reuse_buffers=reuse_buffers,
                 build_all=False,
-                width_multiple=512,
+                width_multiple=_WIDTH_MULTIPLE,
             )
             return batch, self.device
         data_ranks = self.sharded.ranks.get("data", 1)
@@ -1540,7 +1586,7 @@ class MatchEngine:
             max_header=self.max_header,
             pad_rows_to=P,
             reuse_buffers=reuse_buffers,
-            width_multiple=512,
+            width_multiple=_WIDTH_MULTIPLE,
         )
         if row_index is not None:
             batch.row_index = row_index

@@ -13,7 +13,6 @@ bit-identical to the direct path.
 from swarm_tpu.sched.buckets import (  # noqa: F401
     BucketPlanner,
     PlannedBatch,
-    width_class,
 )
 from swarm_tpu.sched.scheduler import (  # noqa: F401
     BatchScheduler,

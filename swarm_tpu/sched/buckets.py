@@ -9,9 +9,10 @@ planner re-bins incoming rows into a SMALL FIXED set of
 
 - width classes are multiples of ``width_multiple`` (512 → 1024 →
   1536 → …, capped at the engine's stream caps), keyed by the row's
-  body/banner length and header length — the same rounding as
-  ``encoding._width_for``, so a bucket's encoded width IS its class
-  and each bucket pins exactly one compiled shape;
+  body/banner length and header length — ``encoding.row_width_class``,
+  the rounding ``encoding._width_for`` encodes with, so a bucket's
+  encoded width IS its class and each bucket pins exactly one compiled
+  shape;
 - a bucket flushes when it reaches ``rows_target`` rows (a full,
   width-homogeneous device batch) or at end of stream (the partial
   final flush, which pays padding only once per bucket per scan
@@ -41,22 +42,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Iterator, Optional
 
-
-def width_class(n: int, multiple: int = 512, cap: int = 4096) -> int:
-    """Smallest multiple of ``multiple`` that holds ``n`` bytes, capped
-    at ``cap`` (rows past the cap truncate on device and host-redo
-    exactly — same contract as encoding). EXACTLY mirrors
-    ``encoding._width_for`` for a batch whose longest row is ``n``:
-    every row in a bucket has length ≤ the class and > class-multiple
-    (for the batch max), so the encoded width IS the class — the
-    planned bucket pins the compiled shape. (A coarser ladder, e.g.
-    powers of two, would NOT pin it: a w2048 bucket whose batch max
-    happened to be 1100 would encode at 1536 and leak extra jit
-    shapes.)"""
-    if n <= multiple:
-        return multiple
-    w = ((n + multiple - 1) // multiple) * multiple
-    return min(w, cap)
+from swarm_tpu.ops.encoding import row_width_class
 
 
 @dataclasses.dataclass
@@ -160,12 +146,9 @@ class BucketPlanner:
         """(body width class, header width class) — in lockstep with
         ``encoding.encode_batch`` part semantics ("body" is the banner
         when one is set)."""
-        blob = row.body if row.banner is None else row.banner
-        wb = width_class(len(blob), self.width_multiple, self.max_body)
-        wh = width_class(
-            len(row.header), self.width_multiple, self.max_header
+        return row_width_class(
+            row, self.width_multiple, self.max_body, self.max_header
         )
-        return wb, wh
 
     # ------------------------------------------------------------------
     def add_fresh(

@@ -1,11 +1,13 @@
 """The direct path's one-deep pipeline (``MatchEngine.match``, ``pipeline="off"``).
 
-A call of several chunks launches chunk i+1's kernels (``begin_packed``)
-before it walks chunk i (``finish_packed``). Results, extractions and
-the verdict memo's contents after the call equal the scheduler path's,
-the CPU oracle's and those of the old serial order (``match_packed`` per
-chunk) — on fresh rows, on rows repeated across chunks, with dead rows,
-and with the device failing from the second chunk's launch on.
+A call of several chunks is cut in width order (rows stable-sorted by
+width class, widest first), and launches chunk i+1's kernels
+(``begin_packed``) before it walks chunk i (``finish_packed``). Results,
+extractions and the verdict memo's contents after the call equal the
+scheduler path's, the CPU oracle's and those of the same chunks matched
+serially (``match_packed`` per chunk) — on fresh rows, on rows repeated
+across chunks, with dead rows, on short and long rows mixed, and with
+the device failing from the second chunk's launch on.
 """
 
 from __future__ import annotations
@@ -20,6 +22,7 @@ import pytest
 from swarm_tpu.fingerprints import load_corpus
 from swarm_tpu.fingerprints.model import Response
 from swarm_tpu.ops import cpu_ref
+from swarm_tpu.ops.encoding import row_width_class
 from swarm_tpu.ops.engine import MatchEngine
 from swarm_tpu.resilience.faults import clear_plan, install_plan
 from tests.test_match_parity import fuzz_rows
@@ -35,7 +38,48 @@ def templates():
     return templates
 
 
+def _sized(row: Response, n: int) -> Response:
+    """``row`` with its body (its banner, for a banner row) cut or
+    padded with page text to ``n`` bytes."""
+    blob = row.body if row.banner is None else row.banner
+    blob = (blob + b" <p>padding of a long page</p>" * (n // 30 + 1))[:n]
+    if row.banner is None:
+        return dataclasses.replace(row, body=blob)
+    return dataclasses.replace(row, banner=blob)
+
+
+def _mixed_rows(templates, seed: int, count: int, every=5) -> list:
+    """Short rows (body class 512) with every ``every``-th row a long
+    page (class 1536 or 2048; none where ``every`` is None): in the
+    caller's order each chunk holds long rows and would encode at their
+    width."""
+    rows = fuzz_rows(templates, random.Random(seed), count)
+    return [
+        _sized(
+            r,
+            1100 + 300 * (i % 3) if every and i % every == 2
+            else min(r.content_length, 500),
+        )
+        for i, r in enumerate(rows)
+    ]
+
+
+def _width_ordered(rows) -> list:
+    """Indices of ``rows`` in the direct path's chunk order: stable by
+    width class, widest first."""
+    return sorted(
+        range(len(rows)), key=lambda i: row_width_class(rows[i]),
+        reverse=True,
+    )
+
+
 def _case_rows(templates, case: str) -> list:
+    if case.startswith("mixed"):
+        base = _mixed_rows(templates, 37, 40)
+        if case == "mixed-dead":
+            for k in (0, 2, 17, 33, 39):
+                base.insert(k, Response(host=f"dead{k}", alive=False))
+        return base
     base = fuzz_rows(templates, random.Random(31), 40)
     if case == "repeated":
         # chunks 1 and 2 carry chunk 0's content again, under other hosts
@@ -59,16 +103,24 @@ def _oracle(templates, rows) -> list:
 
 
 def _serial(eng, rows) -> list:
-    """The old direct order: match_packed chunk by chunk, no overlap."""
+    """The direct path's chunks without the overlap: the live rows in
+    width order, match_packed chunk by chunk, answers put back in the
+    caller's positions."""
     live = [r for r in rows if r.alive]
-    out = iter([
+    order = _width_ordered(live)
+    ordered = [live[i] for i in order]
+    matched = [
         m
-        for s in range(0, len(live), eng.batch_rows)
+        for s in range(0, len(ordered), eng.batch_rows)
         for m in eng.rowmatches_from_packed(
-            eng.match_packed(live[s : s + eng.batch_rows]),
-            len(live[s : s + eng.batch_rows]),
+            eng.match_packed(ordered[s : s + eng.batch_rows]),
+            len(ordered[s : s + eng.batch_rows]),
         )
-    ])
+    ]
+    by_live: list = [None] * len(live)
+    for pos, i in enumerate(order):
+        by_live[i] = matched[pos]
+    out = iter(by_live)
     return [next(out) if r.alive else None for r in rows]
 
 
@@ -85,10 +137,14 @@ def _run(templates, rows, fault: bool, serial: bool = False):
     return eng, got
 
 
-@pytest.mark.parametrize("case", ["fresh", "repeated", "dead", "fault"])
+@pytest.mark.parametrize(
+    "case",
+    ["fresh", "repeated", "dead", "fault", "mixed", "mixed-dead",
+     "mixed-fault"],
+)
 def test_direct_pipeline_parity(templates, case):
     rows = _case_rows(templates, case)
-    fault = case == "fault"
+    fault = case.endswith("fault")
     sched = MatchEngine(templates, mesh=None, batch_rows=ROWS, pipeline="on")
     want = sched.match(rows)
     eng, got = _run(templates, rows, fault)
@@ -153,3 +209,86 @@ def test_sharded_direct_pipeline_matches_single_device(templates):
     assert [(m.template_ids, m.extractions) for m in got] == [
         (m.template_ids, m.extractions) for m in one
     ]
+
+
+def _spied(eng) -> tuple:
+    """(chunks, widths): the rows of each chunk the engine encodes, and
+    the body width of each device dispatch, in order."""
+    eng._resolve_backend()
+    chunks: list = []
+    widths: list = []
+    encode, dispatch = eng._encode_for_backend, eng.device.dispatch
+
+    def encode_spy(rows, *a, **kw):
+        chunks.append(list(rows))
+        return encode(rows, *a, **kw)
+
+    def dispatch_spy(streams, *a, **kw):
+        widths.append(int(streams["body"].shape[1]))
+        return dispatch(streams, *a, **kw)
+
+    eng._encode_for_backend = encode_spy
+    eng.device.dispatch = dispatch_spy
+    return chunks, widths
+
+
+@pytest.mark.parametrize(
+    "case, narrowed, want_widths",
+    [
+        # 8 long rows (classes 2048 and 1536) go first, with the first 8
+        # short rows; the 24 others fill two chunks at width 512
+        ("mixed", 2, [2048, 512, 512]),
+        # every row in class 512: the caller's chunks
+        ("one-class", 0, [512, 512, 512]),
+        # 12 rows are one chunk: the caller's order, at its widest row
+        ("one-chunk", 0, [2048]),
+    ],
+)
+def test_width_order_narrows_chunks(templates, case, narrowed, want_widths):
+    if case == "mixed":
+        rows = _mixed_rows(templates, 38, 40)
+    elif case == "one-class":
+        rows = _mixed_rows(templates, 38, 40, every=None)
+    else:
+        rows = _mixed_rows(templates, 38, 12)
+    eng = MatchEngine(templates, mesh=None, batch_rows=ROWS)
+    chunks, widths = _spied(eng)
+    got = eng.match(rows)
+
+    order = _width_ordered(rows) if case == "mixed" else range(len(rows))
+    ordered = [rows[i] for i in order]
+    assert chunks == [
+        ordered[s : s + ROWS] for s in range(0, len(rows), ROWS)
+    ]
+    assert widths == want_widths
+    assert eng.stats.narrowed_chunks == narrowed
+    assert [sorted(m.template_ids) for m in got] == _oracle(templates, rows)
+
+    if case == "mixed":
+        # in the caller's order every chunk holds a long row
+        ref = MatchEngine(templates, mesh=None, batch_rows=ROWS)
+        _c, caller_widths = _spied(ref)
+        for s in range(0, len(rows), ROWS):
+            ref.match_packed(rows[s : s + ROWS])
+        assert caller_widths == [2048, 2048, 2048]
+        # a second call of the same rows is memo-served: it encodes no
+        # chunk, and still counts the chunks it cut narrower
+        eng.match(rows)
+        assert widths == want_widths
+        assert eng.stats.narrowed_chunks == 2 * narrowed
+
+
+def test_sharded_direct_pipeline_mixed_widths_match_single_device(templates):
+    """On a mesh the width-ordered chunks give the single device's
+    answers too, in the caller's order, with dead rows among them."""
+    rows = _case_rows(templates, "mixed-dead")
+    one = MatchEngine(templates, mesh=None, batch_rows=ROWS).match(rows)
+    eng = MatchEngine(templates, mesh="auto", batch_rows=ROWS)
+    got = eng.match(rows)
+    assert eng.sharded is not None
+    assert eng.stats.narrowed_chunks == 2
+    assert eng.stats.overlapped_batches == 2
+    assert [(m.template_ids, m.extractions) for m in got] == [
+        (m.template_ids, m.extractions) for m in one
+    ]
+    assert [sorted(m.template_ids) for m in got] == _oracle(templates, rows)

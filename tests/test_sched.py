@@ -20,11 +20,11 @@ import numpy as np
 import pytest
 
 from swarm_tpu.fingerprints.model import Response
+from swarm_tpu.ops.encoding import _width_for, width_class
 from swarm_tpu.sched import (
     BatchScheduler,
     BucketPlanner,
     SchedulerConfig,
-    width_class,
 )
 
 # ----------------------------------------------------------------------
@@ -43,8 +43,6 @@ def test_width_class_mirrors_encoder_rounding():
     assert width_class(3000, multiple=512, cap=2048) == 2048
     # lockstep with the encoder: a bucket's encoded width is its class,
     # so each bucket pins exactly one compiled shape
-    from swarm_tpu.ops.encoding import _width_for
-
     for n in (0, 1, 511, 512, 513, 1100, 1536, 2047, 4000, 9999):
         assert width_class(n) == _width_for(
             np.asarray([n]), cap=4096, multiple=512

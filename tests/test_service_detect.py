@@ -8,6 +8,7 @@ with the device match engine as prefilter.
 
 from __future__ import annotations
 
+import re
 import socketserver
 import threading
 
@@ -58,6 +59,20 @@ def test_parse_probes_structure():
     assert ssh.version == "$2" and ssh.info == "protocol $1"
     assert ssh.cpe == ["a:openbsd:openssh:$2"]
     assert null.matches[1].soft
+
+
+def test_match_regex_is_compiled_once():
+    """The loader's check compiles each directive's regex once; the
+    classifier reuses that pattern (re's own cache holds 512 patterns,
+    a DB at nmap's scale ~12k, so a second compile is paid again)."""
+    probes, _ = parse_probes(MINI_DB)
+    matches = [m for p in probes for m in p.matches]
+    first = [m.compile() for m in matches]
+    assert all(first)
+    re.purge()
+    clf = ServiceClassifier(probes=probes)
+    assert all(a is b for a, b in zip(clf._compiled, first, strict=True))
+    assert all(m.compile() is c for m, c in zip(matches, first))
 
 
 def test_unescape_payload():
